@@ -22,6 +22,7 @@ from repro.kernels.moe_gemm import grouped_expert_ffn, grouped_expert_matmul
 from repro.kernels.paged_attention import (
     paged_decode_gqa,
     paged_decode_gqa_ref,
+    resolve_backend,
 )
 
 
@@ -121,7 +122,7 @@ def bench_paged_attention():
     )
     us_full, us_sparse, w = time_full_vs_sparse(q, pool_k, pool_v, tables, pos)
     got = paged_decode_gqa(q, pool_k, pool_v, tables[:, :w], pos,
-                           interpret=True)
+                           interpret=resolve_backend("pallas").interpret)
     ref = paged_decode_gqa_ref(q, pool_k, pool_v, tables[:, :w], pos)
     err = float(jnp.max(jnp.abs(got - ref)))
     # the dense path moves nb/w x the K/V bytes per step

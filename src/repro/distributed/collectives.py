@@ -49,13 +49,12 @@ def cross_pod_grad_reduce(grads, mesh: Mesh, method: str = "int8"):
     compressed wire format (intra-pod reduction is left to XLA/SPMD)."""
     if "pod" not in mesh.shape:
         return grads
-    from jax.experimental.shard_map import shard_map
-
     def reduce_leaf(g):
         spec = P(*([None] * g.ndim))
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=spec, out_specs=spec, check_rep=False
+            jax.shard_map, mesh=mesh, in_specs=spec, out_specs=spec,
+            check_vma=False,
         )
         def f(x):
             return compressed_psum(x / mesh.shape["pod"], "pod", method)

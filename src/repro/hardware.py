@@ -1,10 +1,12 @@
 """Hardware constants.
 
 ``TRIMOE_HW`` is the paper's Table 1 prototype (H100 PCIe + AMX Xeon 8470
-+ 16 buffer-chip DIMM-NDPs + DIMM-Link). ``TPU_V5E`` is the dry-run /
-roofline target. Derived quantities (per-DIMM host bandwidth, aggregate
-NDP bandwidth) follow the paper's stated ratios: NDP internal bandwidth is
-8x the host's view of a single DIMM, and a full-NDP system aggregates
++ 16 buffer-chip DIMM-NDPs + DIMM-Link). ``TPU_V5E`` is the serving
+and roofline target; ``tpu_spec`` finds a chip's constants by the
+``device_kind`` JAX reports. Derived quantities (per-DIMM host
+bandwidth, aggregate NDP bandwidth) follow the paper's stated ratios:
+NDP internal bandwidth is 8x the host's view of a single DIMM, and a
+full-NDP system aggregates
 16 x 153.6 GB/s = 2.46 TB/s — the physics that makes cold-expert
 offloading win.
 """
@@ -55,8 +57,10 @@ class TriMoEHardware:
 
 @dataclass(frozen=True)
 class TPUv5e:
-    """Roofline constants for the dry-run target (per chip)."""
+    """Per-chip constants of a TPU v5e (Google Cloud documentation,
+    "TPU v5e"); `device_kind` is what JAX reports for the chip."""
 
+    device_kind: str = "TPU v5 lite"
     flops: float = 197e12  # BF16 FLOP/s
     hbm_bw: float = 819e9  # B/s
     hbm_bytes: float = 16e9
@@ -67,3 +71,18 @@ class TPUv5e:
 
 TRIMOE_HW = TriMoEHardware()
 TPU_V5E = TPUv5e()
+# TPU chips this repository has constants for, keyed by `device_kind`
+TPU_BY_KIND = {TPU_V5E.device_kind: TPU_V5E}
+
+
+def tpu_spec(device_kind: str) -> TPUv5e:
+    """The constants of a TPU chip by its JAX `device_kind`. A kind not
+    in `TPU_BY_KIND` is an error: sizing a tier for the wrong HBM would
+    go unnoticed until the chip ran out of memory."""
+    try:
+        return TPU_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware constants for TPU device_kind {device_kind!r}; "
+            f"known kinds: {sorted(TPU_BY_KIND)} (repro.hardware)"
+        ) from None

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref):
     f_idx = pl.program_id(0)
@@ -68,7 +66,7 @@ def expert_ffn_gemv(
         out_specs=pl.BlockSpec((t, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(tile_expert_ref, x_ref, w_ref, o_ref):
     # tile_expert_ref is scalar-prefetch (consumed by index maps only)
@@ -58,7 +56,7 @@ def moe_gemm(
             out_specs=pl.BlockSpec((bm, bn), lambda m, n, te: (m, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

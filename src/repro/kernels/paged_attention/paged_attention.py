@@ -33,8 +33,10 @@ causally visible to every query, so the denominator never collapses —
 this also covers all-pad prefill rows whose `lengths` is 0).
 
 Two variants:
-  * GQA — pools [N+1, bs, Kv, hd]; queries grouped per KV head so the
-    MQA/GQA head-sharing reads each K/V block once per kv head;
+  * GQA — pools [N+1, bs, Kv, hd]; each grid step loads one whole
+    block, every kv head included (the TPU needs a block's last two
+    dims to be the pool's own), and queries are grouped per kv head so
+    the MQA/GQA head-sharing reads each K/V block once;
   * MLA — absorbed attention over the (ckv, krope) latent pool layout;
     scores are q_lat . ckv + q_rope . krope and the output is the
     latent-space attention read (o_lat), with the wv_b expansion left
@@ -49,16 +51,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 # ------------------------------------------------------------------- GQA
 def _gqa_kernel(tables_ref, past_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, bs, c, g):
+                m_ref, l_ref, acc_ref, *, bs, g):
     del tables_ref  # consumed by the BlockSpec index maps only
-    b, j = pl.program_id(0), pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -74,31 +74,39 @@ def _gqa_kernel(tables_ref, past_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # still produce a finite (discarded) output
     @pl.when((j == 0) | (j * bs <= last))
     def _block():
-        q = q_ref[0, :, 0].reshape(c * g, q_ref.shape[-1])  # [C*G, hd]
-        k = k_ref[0, :, 0, :]                               # [bs, hd]
-        v = v_ref[0, :, 0, :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s *= q.shape[-1] ** -0.5
+        shape = (q_ref.shape[2], bs)  # [C*G, bs] scores per kv head
         # causal masking at per-query absolute positions: query row
         # r covers chunk token r // G sitting at past + r // G
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qpos = past + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        qpos = past + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
+        visible = kpos <= qpos
+        # the block carries every kv head (the TPU tiling needs the
+        # pool's last two dims whole), so heads are a static loop
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]           # [C*G, hd]
+            k = k_ref[0, :, h, :]     # [bs, hd]
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            s *= q.shape[-1] ** -0.5
+            s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+            m_ref[h] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _done():
-        o_ref[0, :, 0] = (
+        o_ref[0] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).reshape(c, g, o_ref.shape[-1]).astype(o_ref.dtype)
+        ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -115,39 +123,43 @@ def paged_prefill_gqa(
     b, c, kv, g, hd = q.shape
     bs = pool_k.shape[1]
     nb = tables.shape[1]
-    kern = functools.partial(_gqa_kernel, bs=bs, c=c, g=g)
-    return pl.pallas_call(
+    # head-major query tile [B, Kv, C*G, hd]: one kv head's queries are
+    # a contiguous 2-D slab in the kernel (row r = chunk token r // G)
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(b, kv, c * g, hd)
+    kern = functools.partial(_gqa_kernel, bs=bs, g=g)
+    out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, kv, nb),
+            grid=(b, nb),
             in_specs=[
                 pl.BlockSpec(
-                    (1, c, 1, g, hd), lambda bi, h, j, t, p, n: (bi, 0, h, 0, 0)
+                    (1, kv, c * g, hd), lambda bi, j, t, p, n: (bi, 0, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, bs, 1, hd), lambda bi, h, j, t, p, n: (t[bi, j], 0, h, 0)
+                    (1, bs, kv, hd), lambda bi, j, t, p, n: (t[bi, j], 0, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, bs, 1, hd), lambda bi, h, j, t, p, n: (t[bi, j], 0, h, 0)
+                    (1, bs, kv, hd), lambda bi, j, t, p, n: (t[bi, j], 0, 0, 0)
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (1, c, 1, g, hd), lambda bi, h, j, t, p, n: (bi, 0, h, 0, 0)
+                (1, kv, c * g, hd), lambda bi, j, t, p, n: (bi, 0, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, 1), jnp.float32),
-                pltpu.VMEM((c * g, hd), jnp.float32),
+                pltpu.VMEM((kv, c * g, 1), jnp.float32),
+                pltpu.VMEM((kv, c * g, 1), jnp.float32),
+                pltpu.VMEM((kv, c * g, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, c, kv, g, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((b, kv, c * g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(past_len, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), q, pool_k, pool_v)
+      jnp.asarray(lengths, jnp.int32), qh, pool_k, pool_v)
+    return out.reshape(b, kv, c, g, hd).transpose(0, 2, 1, 3, 4)
 
 
 def paged_decode_gqa(
@@ -258,7 +270,7 @@ def paged_prefill_mla(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, c, h, r), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -12,6 +12,8 @@ group steps.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -20,6 +22,23 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.models.model import init_params
 from repro.serving.batching import Request
 from repro.serving.loop import ServingLoop
+
+
+# <checkout>/.jax_cache — git-ignored, and fixed: the cache directory
+# is part of what a later run must match to find an entry again
+_REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. A `JAX_COMPILATION_CACHE_DIR` from the environment is
+    JAX's own setting and wins untouched; otherwise the cache lives at
+    the fixed `<checkout>/.jax_cache`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE_DIR))
+    return str(_REPO_CACHE_DIR)
 
 
 def build_loop(cfg, *, batch: int, groups: int, cache_len: int,
@@ -68,6 +87,7 @@ def main(argv=None):
                     help="admit a partial same-bucket cohort after this many "
                          "admission rounds (starvation cap)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

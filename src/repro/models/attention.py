@@ -146,7 +146,6 @@ def _seq_parallel_attention(q, k, v, *, q_chunk: int):
     """shard_map causal self-attention: query rows sharded over the model
     axis, K/V gathered once per layer. Returns None when shapes don't
     divide (caller falls back to the replicated path)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axis, dp = _SEQ_PARALLEL
@@ -169,9 +168,9 @@ def _seq_parallel_attention(q, k, v, *, q_chunk: int):
 
     spec_q = P(bspec, axis, None, None)
     spec_kv = P(bspec, None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec_q, spec_kv, spec_kv),
-        out_specs=spec_q, check_rep=False,
+        out_specs=spec_q, check_vma=False,
     )
     return fn(q, k, v)
 

@@ -307,13 +307,10 @@ class TriMoEServingEngine:
 
         self._verify_paged = jax.jit(verify_paged_fn)
         self.prefill_rows = prefill_rows
-        # (rows, bucket width, table width) fallback compile count
-        self._prefill_shapes = set()
         self.decode_table_widths = set()  # distinct sliced widths (pow2)
         self.prefill_table_widths = set()  # paged prefill's sliced widths
         self.verify_widths = set()  # pow2-padded chunk-of-k widths
         self.verify_table_widths = set()  # verify's sliced table widths
-        self._verify_shapes = set()  # (chunk width, table width) fallback
         self._migrate = jax.jit(apply_migrations)
 
         # stacked tier buffers migrate in ONE fused jit: extract group g,
@@ -436,7 +433,6 @@ class TriMoEServingEngine:
         assert len(slot_indices) == n and lengths.shape == (n,)
         assert np.all(lengths <= width) and np.all(lengths > 0)
         r = self.prefill_rows
-        self._prefill_shapes.add((r, width, 0))
         out = []
         for c0 in range(0, n, r):
             nr = min(r, n - c0)
@@ -547,7 +543,6 @@ class TriMoEServingEngine:
                 end - 1, self.kv.block_size, max(1, self.kv.blocks_per_slot)
             )
             self.prefill_table_widths.add(tw)
-            self._prefill_shapes.add((r, width, tw))
             toks = np.zeros((r, width), np.int32)
             lens = np.zeros((r,), np.int32)  # dummy rows: all-pad mask
             past = np.zeros((r,), np.int32)
@@ -629,7 +624,6 @@ class TriMoEServingEngine:
         )
         self.verify_widths.add(kw)
         self.verify_table_widths.add(tw)
-        self._verify_shapes.add((kw, tw))
         # dead rows: all-trash tables + zero mask, like prefill pads
         tables = np.full((n, tw), self.kv.trash, np.int32)
         rows = self.kv.table_rows(slot_indices)[:, :tw]
@@ -669,10 +663,7 @@ class TriMoEServingEngine:
         """Distinct jit compiles of the speculative verify — bounded by
         pow2 chunk widths x table-width buckets (the CI spec gate reads
         this through serving_bench --spec)."""
-        try:
-            return int(self._verify_paged._cache_size())
-        except AttributeError:  # older jax: fall back to shape counting
-            return len(self._verify_shapes)
+        return int(self._verify_paged._cache_size())
 
     @property
     def prefill_compiles(self) -> int:
@@ -682,13 +673,10 @@ class TriMoEServingEngine:
         len(bucket_table) x n_width_buckets(blocks_per_slot), the
         table-width slicing factor) — the quantity the CI compile-count
         gate bounds (benchmarks/serving_bench.py)."""
-        try:
-            return int(
-                self._prefill_masked._cache_size()
-                + self._prefill_paged._cache_size()
-            )
-        except AttributeError:  # older jax: fall back to shape counting
-            return len(self._prefill_shapes)
+        return int(
+            self._prefill_masked._cache_size()
+            + self._prefill_paged._cache_size()
+        )
 
     # ---------------------------------------------------------- migration
     def _tier_cost(self, tier: int, load: float) -> float:

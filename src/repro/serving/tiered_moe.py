@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.hardware import TPU_V5E
+from repro.hardware import TPU_V5E, tpu_spec
 from repro.models.layers import Params, dense_init
 from repro.models.moe import expert_ffn, moe_backend, router_topk, shared_ffn
 
@@ -109,11 +109,21 @@ def tier_sizes(cfg, n_chips: Optional[int] = None, hbm_budget_frac: float = 0.15
     mo = cfg.moe
     w_bytes = 3 * cfg.d_model * mo.d_expert * 2
     n_moe_layers = max(1, sum(cfg.uses_moe_layer(i) for i in range(cfg.n_layers)))
-    budget = TPU_V5E.hbm_bytes * hbm_budget_frac + max(0, reclaimed_kv_bytes)
+    budget = _hbm_bytes() * hbm_budget_frac + max(0, reclaimed_kv_bytes)
     n_hot = max(1, min(mo.n_experts // 4, int(budget / (w_bytes * n_moe_layers))))
     n_warm = max(1, min(mo.n_experts - n_hot - 1, int(round(0.30 * mo.n_experts))))
     n_cold = mo.n_experts - n_hot - n_warm
     return validate_tier_sizes(cfg, TierSizes(n_hot, n_warm, n_cold))
+
+
+def _hbm_bytes() -> float:
+    """HBM of the chip serving runs on. On a TPU it comes from the
+    `device_kind` table (an unknown kind raises); off-TPU (CPU tests)
+    there is no HBM to read, so the v5e constant stands in."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return tpu_spec(dev.device_kind).hbm_bytes
+    return TPU_V5E.hbm_bytes
 
 
 def init_tiered_state(rng, cfg, sizes: TierSizes, pad_cold_to: int = 16) -> Params:

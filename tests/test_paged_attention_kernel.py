@@ -402,10 +402,19 @@ def test_engine_slices_tables_to_pow2_active_width(serve_setup):
 @pytest.mark.slow
 def test_serving_identical_across_backends(serve_setup):
     """Serving with the Pallas kernel (interpret on CPU) is
-    token-for-token identical to the dense-gather backend."""
+    token-for-token identical to the dense-gather backend.
+
+    Run at fp32 params: in bf16 the kernel's fp32 online softmax and the
+    reference's bf16-rounded scores differ by an ulp, which can flip a
+    near-tied greedy argmax and diverge the stream without any kernel
+    bug. In fp32 the two agree to rounding and token identity is the
+    robust invariant."""
+    from repro.models.model import init_params
     from repro.serving.batching import Request
 
-    cfg, params = serve_setup
+    cfg, _ = serve_setup
+    cfg = dataclasses.replace(cfg, param_dtype="float32")
+    params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(17)
     reqs = [
         Request(

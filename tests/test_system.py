@@ -1,6 +1,8 @@
 """End-to-end behaviour tests for the TriMoE system."""
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,8 @@ import pytest
 from repro.configs import get_config, reduce_for_smoke
 from repro.core import simulate
 from repro.core.simulator import SimFlags
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def test_paper_headline_claims_hold():
@@ -44,16 +48,80 @@ def test_train_loop_end_to_end(tmp_path):
     assert len(losses2) == 4
 
 
-def test_serve_loop_end_to_end():
+def test_serve_loop_end_to_end(monkeypatch, tmp_path):
     """launch/serve.py decodes with the tiered runtime + migrations."""
     from repro.launch.serve import main
 
+    # a set JAX_COMPILATION_CACHE_DIR leaves JAX's (already read) config
+    # alone, so this run writes no compile cache into the checkout
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     generated = main([
         "--arch", "granite-moe-1b-a400m", "--smoke",
         "--requests", "2", "--batch", "2",
         "--prompt-len", "8", "--new-tokens", "4",
     ])
     assert generated >= 8
+
+
+def test_compile_cache_dir_is_env_or_fixed_checkout_dir(monkeypatch, tmp_path):
+    """The environment's JAX_COMPILATION_CACHE_DIR wins with nothing set
+    in code; without it the cache is <checkout>/.jax_cache, a path that
+    does not change from run to run."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.serve import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = enable_compile_cache()
+        assert path == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
+def test_chip_smoke_refuses_to_run_off_tpu():
+    """chip_smoke.py serves only on a TPU: on the CPU it exits nonzero
+    before building anything and prints no result line."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")], env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_checks_pass_at_smoke_scale(monkeypatch, capsys):
+    """The chip smoke's serve-and-check path, rehearsed at a tiny size
+    with the kernels in interpret mode, so a change that would break it
+    on the chip breaks here first."""
+    import dataclasses
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py")
+    cs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    for name, value in (("N_REQUESTS", 4), ("PROMPT_LENS", (64, 96)),
+                        ("SHARED_PREFIX", 32), ("NEW_TOKENS", 8),
+                        ("CACHE_LEN", 104)):
+        monkeypatch.setattr(cs, name, value)
+    cfg = dataclasses.replace(
+        reduce_for_smoke(get_config(cs.ARCH)),
+        paged_attn_backend="pallas", moe_backend="pallas",
+    )
+    cs.run(cfg, seed=0, kernel_backend="pallas", interpret=True)
+    out = capsys.readouterr().out
+    assert "FAILED" not in out
+    assert "timed pass: requests=4 tokens=32" in out
 
 
 def test_zigzag_batcher_lifecycle():

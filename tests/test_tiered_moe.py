@@ -75,6 +75,26 @@ def test_tier_sizes_fit_hbm_budget():
     assert 1 <= s.n_warm <= cfg.moe.n_experts
 
 
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v4"])
+def test_tier_sizes_take_hbm_from_the_tpu_kind(monkeypatch, kind):
+    """On a TPU the hot budget comes from the device_kind table, and a
+    kind the table does not know is an error, not a v5e default."""
+    from repro.hardware import TPU_V5E
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = kind
+
+    cfg = get_config("granite-moe-1b-a400m")
+    on_cpu = tier_sizes(cfg, n_chips=1)
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
+    if kind != TPU_V5E.device_kind:
+        with pytest.raises(ValueError, match="TPU v4"):
+            tier_sizes(cfg, n_chips=1)
+    else:
+        assert tier_sizes(cfg, n_chips=1) == on_cpu
+
+
 def test_engine_online_loop_runs():
     from repro.models.model import init_params, prefill
     from repro.serving.engine import (

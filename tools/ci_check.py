@@ -15,8 +15,9 @@ one pytest node id per line, '#' comments allowed). CI fails on:
     workload traces (*.trace.npz), and observability exports
     (*.trace.json / *.perfetto.json Perfetto timelines,
     metrics_snapshot.{json,prom} dumps from tools/export_trace.py),
-    and the repro-lint findings report (repro_lint_report.json from
-    `python -m tools.analysis --report`) must never be committed
+    the repro-lint findings report (repro_lint_report.json from
+    `python -m tools.analysis --report`), and JAX's compile cache
+    (.jax_cache/) must never be committed
     (bytecode was once, by accident; .gitignore plus this gate keeps
     all of them out).
 
@@ -67,6 +68,10 @@ def _is_artifact(path: str) -> bool:
     BENCH_serving.json baseline is NOT an artifact — only the
     *_mixed/*_nightly scratch files CI regenerates every run are."""
     if "__pycache__" in path or path.endswith((".pyc", ".pyo")):
+        return True
+    if path.startswith(".jax_cache/"):
+        # JAX's persistent compile cache (repro.launch.serve.
+        # enable_compile_cache), rebuilt by every machine that compiles
         return True
     name = path.rsplit("/", 1)[-1]
     if name == "report.xml" or name.endswith(".junit.xml"):
