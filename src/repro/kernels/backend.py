@@ -147,6 +147,6 @@ def kernel_span(op: str, backend: KernelBackend):
     if tr is None or not tr.enabled:
         return _NULL_SPAN
     return tr.span(
-        f"kernel.{op}", cat="kernel",
+        f"kernel.{op}",
         backend=backend.kind, interpret=bool(backend.interpret),
     )

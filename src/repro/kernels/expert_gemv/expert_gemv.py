@@ -56,6 +56,7 @@ def expert_ffn_gemv(
 
     return pl.pallas_call(
         _kernel,
+        name="expert_gemv",
         grid=grid,
         in_specs=[
             pl.BlockSpec((t, d), lambda i: (0, 0)),  # tokens resident

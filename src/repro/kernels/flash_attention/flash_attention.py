@@ -80,6 +80,7 @@ def flash_attention(
     kern = functools.partial(_kernel, causal=causal, bq=bq, bk=bk)
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, qi, ki: (b, qi, 0)),

@@ -46,6 +46,7 @@ def moe_gemm(
 
     return pl.pallas_call(
         _kernel,
+        name="moe_gemm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -129,6 +129,7 @@ def paged_prefill_gqa(
     kern = functools.partial(_gqa_kernel, bs=bs, g=g)
     out = pl.pallas_call(
         kern,
+        name="paged_prefill_gqa",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nb),
@@ -247,6 +248,7 @@ def paged_prefill_mla(
     kern = functools.partial(_mla_kernel, bs=bs, c=c, h=h, scale=scale)
     return pl.pallas_call(
         kern,
+        name="paged_prefill_mla",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nb),

@@ -1,28 +1,35 @@
-"""Structured tracing to Chrome/Perfetto `trace_event` JSON.
+"""Structured tracing: Chrome/Perfetto `trace_event` JSON, and the JAX
+profiler's trace on the device's clock.
 
-The Tracer answers "where did this decode step's 143 ms go?": the
-serving loop and engine open nested spans (`step` > `admit` /
-`prefill_chunk` / `decode` > `replan` / `migrate`), the scheduler/tier
-channel records per-tier expert occupancy as counter tracks and
-migration/thrash events as instants on the same timeline, and
-`export()` writes a JSON object format file that
+The serving loop and engine open nested spans, each under one qualified
+name used in both sinks: `serving.step` > `serving.admit` /
+`serving.prefill_chunk` / `serving.decode` > `scheduler.replan` /
+`scheduler.migrate`, with `serving.sync` around each blocking pull of
+sampled tokens and `spec.draft` / `spec.verify` under a speculative
+decode step. The scheduler/tier channel records per-tier expert
+occupancy as counter tracks and migration/thrash events as instants on
+the same timeline, and `export()` writes a JSON object format file that
 https://ui.perfetto.dev (or chrome://tracing) loads directly.
+
+While enabled, each span also enters a `jax.profiler.TraceAnnotation`
+of its name, so under `jax.profiler.trace` the program's spans land in
+the `.xplane.pb` as host events on the same clock as the device ops.
 
 Event phases used (Trace Event Format):
   "X" complete span  — ts + dur (microseconds); nesting is by
                        containment per (pid, tid) track
   "i" instant        — a point event (migrations, thrash)
-  "C" counter        — a stacked counter track (tier occupancy, slots)
+  "C" counter        — a stacked counter track (tier occupancy)
   "M" metadata       — process/thread naming
 
 Overhead contract: a disabled tracer's `span()` returns a shared no-op
 context manager and `instant()`/`counter()` return immediately — no
-event dicts, no clock reads, no allocation beyond the call itself —
-so tracing can stay compiled into the hot path (the serving_bench
-overhead gate runs with tracing disabled). Timestamps are
-`time.perf_counter()` relative to tracer construction, in microseconds.
+event dicts, no clock reads, no profiler annotation, no allocation
+beyond the call itself — so tracing can stay compiled into the hot path.
+JSON timestamps are `time.perf_counter()` relative to tracer
+construction, in microseconds.
 
-Zero dependencies (json/threading/time only).
+Imports jax (for the annotation) only when a span is first entered.
 """
 from __future__ import annotations
 
@@ -48,9 +55,11 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One open "X" span; the event is recorded at __exit__."""
+    """One open "X" span; the event is recorded at __exit__. The profiler
+    annotation of the same name is open between __enter__ and
+    __exit__."""
 
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
         self._tr = tr
@@ -59,17 +68,23 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._tr._now_us()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
+        t1 = tr._now_us()
+        self._ann.__exit__(None, None, None)
         ev: Dict[str, Any] = {
             "name": self.name,
             "cat": self.cat,
             "ph": "X",
             "ts": self._t0,
-            "dur": tr._now_us() - self._t0,
+            "dur": t1 - self._t0,
             "pid": tr.pid,
             "tid": threading.get_ident(),
         }
@@ -99,12 +114,14 @@ class Tracer:
         return (time.perf_counter() - self._t0) * 1e6
 
     # ------------------------------------------------------------- emit
-    def span(self, name: str, cat: str = "serving", **args):
+    def span(self, name: str, **args):
         """Context manager recording a complete ("X") span around the
-        `with` body. No-op (shared NULL_SPAN) when disabled."""
+        `with` body, and a profiler annotation of the same name. `name`
+        is qualified (`serving.step`); its prefix is the JSON category.
+        No-op (shared NULL_SPAN) when disabled."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, name.split(".", 1)[0], args)
 
     def instant(self, name: str, cat: str = "serving", **args) -> None:
         """Point event ("i", thread-scoped) — migrations, thrash."""

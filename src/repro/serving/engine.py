@@ -26,7 +26,7 @@ All scheduling knobs come from one `SchedulerPolicy`
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,9 +52,11 @@ from repro.obs.metrics import RegistryStats
 from repro.serving.kv_cache import SlotKVCache, gather_slots, scatter_slots
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.tiered_moe import (
+    TIER_KEYS,
     TierSizes,
     apply_migrations,
     init_tiered_state,
+    tier_capacity,
     tier_occupancy,
     tier_sizes,
 )
@@ -165,10 +167,29 @@ class EngineStats(RegistryStats):
         "replans": ("passes", "plan_migrations passes over all layers"),
         "thrash_events": (
             "events", "tier flip-flops within policy.thrash_window"),
+        # --- MoE dispatch, from the [L, E] expert counts `observe` gets
+        "moe_steps": ("steps", "steps whose expert counts were observed"),
+        "moe_assignments": ("pairs", "live (token, expert) pairs routed"),
+        "experts_touched": ("cells", "(layer, expert) cells routed to"),
+        "expert_rows": (
+            "rows", "rows the tier FFNs computed: slots x capacity"),
+        "routed_hot": ("pairs", "routed pairs the hot tier served"),
+        "routed_warm": ("pairs", "routed pairs the warm tier served"),
+        "routed_cold": ("pairs", "routed pairs the cold tier served"),
     }
     HISTS = {
         "plan_latency_s": ("s", "host-side plan_migrations latency"),
     }
+
+
+class MoEDispatch(NamedTuple):
+    """What one MoE step was dispatched with, to pair with the expert
+    counts it returns: the tokens each MoE layer dispatched (every tier
+    buffer's capacity follows from it) and the [L, E] `expert_tier`
+    table the step read (the applied layout, not the planner's)."""
+
+    tokens: int
+    tier: np.ndarray
 
 
 class TriMoEServingEngine:
@@ -325,12 +346,30 @@ class TriMoEServingEngine:
 
         self._migrate_stack = jax.jit(migrate_stack)
         self._layer_keys = self._flatten_layer_keys()
-        # persistent host mirror of each layer's (expert_tier, expert_slot),
-        # lazily seeded from device state: planning then never needs a
+        self._layer_index = {key: li for li, key in enumerate(self._layer_keys)}
+        self._moe_rows = self._moe_count_rows()
+        # the routing tables as the device holds them, read once here:
+        # [L, E] expert_tier / expert_slot, replaced (never mutated) by
+        # `apply_planned`, which replays each swap as apply_migrations
+        # does — the layout a step is dispatched with, with no sync
+        tiers, slots = zip(*map(self._read_tables, self._layer_keys))
+        self._applied = (np.stack(tiers), np.stack(slots))
+        # tier buffer slots summed over MoE layers, per tier (a buffer
+        # is [(group,) slots, 3, D, F])
+        self._tier_slots = [
+            sum(self._state_ref(key)[k].shape[-4] for key in self._layer_keys)
+            for k in TIER_KEYS
+        ]
+        self.last_dispatch: Optional[MoEDispatch] = None
+        # persistent host mirror of each layer's (expert_tier,
+        # expert_slot) for planning, which thus never needs a
         # device->host sync. plan_migrations mutates it in lockstep with
-        # the swaps it emits (the apply-before-next-plan assertion keeps
-        # mirror and device from diverging).
-        self._host_layout: Dict[int, tuple] = {}
+        # the swaps it emits, ahead of the device (the
+        # apply-before-next-plan assertion keeps the two from diverging).
+        self._host_layout: Dict[int, tuple] = {
+            li: (tiers[li].copy(), slots[li].copy())
+            for li in range(len(self._layer_keys))
+        }
 
     # cache is owned by the SlotKVCache so the loop and engine share one
     # source of truth; keep attribute-style access for legacy callers.
@@ -355,11 +394,31 @@ class TriMoEServingEngine:
                 keys.append(("stack", s, g))
         return keys
 
-    def _get_state(self, key) -> Params:
-        kind, name, g = key
-        if kind == "layer":
-            return self.tiered[name]
-        return jax.tree.map(lambda a: a[g], self.tiered["stack"][name])
+    def _moe_count_rows(self) -> np.ndarray:
+        """Rows of a step's expert counts that belong to MoE layers, in
+        `_layer_keys` order: the model returns one row per layer
+        (unrolled layers, then each scan group's period), zeros where a
+        layer has no MoE."""
+        unrolled, n_groups, period = stack_plan(self.cfg)
+        rows = [i for i, li in enumerate(unrolled)
+                if layer_signature(self.cfg, li)[1] == "moe"]
+        rows += [len(unrolled) + g * len(period) + j
+                 for g in range(n_groups)
+                 for j, sig in enumerate(period) if sig[1] == "moe"]
+        return np.asarray(rows, np.int64)
+
+    def _state_ref(self, key) -> Params:
+        """The tier state holding layer `key`, its group axis kept (no
+        device op)."""
+        kind, name, _ = key
+        return self.tiered[name] if kind == "layer" else self.tiered["stack"][name]
+
+    def _read_tables(self, key) -> tuple:
+        """Layer `key`'s (expert_tier, expert_slot) read to the host,
+        without slicing the weight buffers on the device."""
+        st = self._state_ref(key)
+        out = tuple(np.array(st[f]) for f in ("expert_tier", "expert_slot"))
+        return out if key[0] == "layer" else tuple(a[key[2]] for a in out)
 
     # ----------------------------------------------------------- stepping
     def step(self, tokens: jnp.ndarray, pos: int):
@@ -367,9 +426,9 @@ class TriMoEServingEngine:
         logits, self.cache, counts = self._step(
             self.params, tokens, self.cache, jnp.asarray(pos, jnp.int32), self.tiered
         )
-        counts = np.asarray(counts)
+        self._dispatched(int(np.prod(tokens.shape)))
         self.stats.steps += 1
-        self.replan(counts)
+        self.replan(counts, self.last_dispatch)
         return logits
 
     def step_slots(self, tokens, pos, slot_indices, live=None):
@@ -392,6 +451,7 @@ class TriMoEServingEngine:
             self.params, jnp.asarray(tokens), self.kv.cache, idx,
             jnp.asarray(pos, jnp.int32), self.tiered, jnp.asarray(live, bool),
         )
+        self._dispatched(len(slot_indices))
         self.stats.steps += 1
         return logits, counts
 
@@ -502,6 +562,7 @@ class TriMoEServingEngine:
             self.kv.slot_state, jnp.asarray(tables), idx,
             jnp.asarray(pos, jnp.int32), self.tiered, jnp.asarray(live, bool),
         )
+        self._dispatched(len(slot_indices))
         self.stats.steps += 1
         return logits, counts
 
@@ -655,6 +716,7 @@ class TriMoEServingEngine:
                 self.kv.slot_state, sub,
                 [int(slot_indices[j]) for j in live_rows],
             )
+        self._dispatched(n * kw)
         self.stats.steps += 1
         return logits, counts
 
@@ -709,13 +771,40 @@ class TriMoEServingEngine:
         hw = self.domains.hw
         return 2.0 * self.shape.weight_bytes / (hw.ici_link_bw * hw.ici_links)
 
-    def observe(self, counts: np.ndarray) -> None:
+    def _dispatched(self, tokens: int) -> None:
+        """Record what the MoE step just dispatched ran with."""
+        self.last_dispatch = MoEDispatch(tokens, self._applied[0])
+
+    def observe(self, counts: np.ndarray,
+                dispatch: Optional[MoEDispatch] = None) -> None:
         """Feed realized per-layer expert loads to the EMA predictor
         (Eq. 8). Runs every step, even under `policy.freeze` — the
-        static baseline still reports predictor accuracy."""
+        static baseline still reports predictor accuracy.
+
+        With the `dispatch` record of the step that produced `counts`
+        (`last_dispatch`, read right after the step), also count what
+        the step's MoE layers did (`engine.moe_*`, `expert_rows`,
+        `routed_*`), on the host from the counts alone."""
         counts = np.asarray(counts)
+        if counts.shape[0] != len(self._layer_keys):  # one row per layer
+            counts = counts[self._moe_rows]
         for li in range(len(self._layer_keys)):
             self.predictor.update(li, counts[li])
+        if dispatch is None:
+            return
+        st, top_k = self.stats, self.cfg.moe.top_k
+        st.moe_steps += 1
+        st.moe_assignments += int(counts.sum())
+        st.experts_touched += int(np.count_nonzero(counts))
+        st.expert_rows += sum(
+            n * tier_capacity(tid, dispatch.tokens, top_k, self.cold_capacity_frac)
+            for tid, n in enumerate(self._tier_slots)
+        )
+        by_tier = np.bincount(dispatch.tier.ravel(), weights=counts.ravel(),
+                              minlength=3)
+        st.routed_hot += int(by_tier[HOT])
+        st.routed_warm += int(by_tier[WARM])
+        st.routed_cold += int(by_tier[COLD])
 
     def plan_migrations(self) -> list:
         """Draw migration plans from the predictor's hysteresis tier
@@ -774,12 +863,6 @@ class TriMoEServingEngine:
         e_idx = np.arange(self.predictor.ema.shape[1])
         for li, key in enumerate(self._layer_keys):
             decided = self.predictor.decided[li]
-            if li not in self._host_layout:
-                state = self._get_state(key)
-                self._host_layout[li] = (
-                    np.array(state["expert_tier"], copy=True),
-                    np.array(state["expert_slot"], copy=True),
-                )
             cur_tier, cur_slot = self._host_layout[li]
             moves = np.nonzero(decided != cur_tier)[0]
             if len(moves) == 0:
@@ -869,7 +952,7 @@ class TriMoEServingEngine:
             self._unapplied = None
             return
         tr = self._tr
-        with tr.span("migrate", cat="scheduler"):
+        with tr.span("scheduler.migrate"):
             for key, plan in plans:
                 kind, name, g = key
                 if tr.enabled:
@@ -887,12 +970,30 @@ class TriMoEServingEngine:
                     self.tiered["stack"][name] = self._migrate_stack(
                         self.tiered["stack"][name], jnp.asarray(plan), g
                     )
+            self._applied = _swapped(self._applied, [
+                (self._layer_index[key], plan) for key, plan in plans
+            ])
         self._unapplied = None
 
-    def replan(self, counts: np.ndarray) -> None:
+    def replan(self, counts: np.ndarray,
+               dispatch: Optional[MoEDispatch] = None) -> None:
         """Legacy synchronous path: observe + plan + apply in one call
         (`engine.step` and pre-PR-7 callers)."""
-        self.observe(counts)
+        self.observe(counts, dispatch)
         self.apply_planned(self.plan_migrations())
 
     _replan = replan  # legacy name
+
+
+def _swapped(tables: tuple, plans: list) -> tuple:
+    """[L, E] (expert_tier, expert_slot) after the swaps of `plans`
+    ([(layer index, [M, 5] plan)]), row by row as `apply_migrations`
+    updates a layer's tables; new arrays, the inputs untouched."""
+    tier, slot = tables[0].copy(), tables[1].copy()
+    for li, plan in plans:
+        t, s = tier[li], slot[li]
+        for ea, ta, sa, tb, sb in plan[plan[:, 0] >= 0]:
+            occupant = int(np.argmax((t == tb) & (s == sb)))
+            t[ea], s[ea] = tb, sb
+            t[occupant], s[occupant] = ta, sa
+    return tier, slot

@@ -103,6 +103,9 @@ class LoopStats(RegistryStats):
         # inter-token latency: gap between a request's consecutive tokens
         "itl_s": ("s", "inter-token latency between consecutive tokens"),
         "plan_s": ("s", "host-side migration-planning latency"),
+        # submit -> dispatch of the request's first prefill computation:
+        # the batcher queue and the piggyback prefill queue together
+        "queue_wait_s": ("s", "submit -> first prefill computation"),
     }
 
     def __init__(self, registry=None):
@@ -206,6 +209,7 @@ class _PrefillTask:
     slot: int
     req: Request
     done: int
+    started: bool = False  # a chunk of it has been dispatched
 
 
 class ServingLoop:
@@ -423,7 +427,8 @@ class ServingLoop:
         self._t_last_tok: Dict[int, float] = {}
         self._slot_req: Dict[int, Request] = {}  # paged: slot -> request
         self._prefill_tasks: List[_PrefillTask] = []  # FIFO piggyback queue
-        self._pending_counts = None  # previous group's realized loads
+        # previous group's realized loads and its engine.last_dispatch
+        self._pending_counts = None
         self._planned: list = []  # plans drawn but not yet applied
         self._steps_since_replan = 0  # policy.replan_every cadence
 
@@ -462,8 +467,16 @@ class ServingLoop:
             self.kv.free_slot(i, tokens=toks)
 
     def _admit(self) -> None:
-        with self._tr.span("admit"):
+        with self._tr.span("serving.admit"):
             self._admit_inner()
+
+    def _prefill_dispatched(self, reqs) -> None:
+        """`reqs` have their first prefill computation dispatched now."""
+        now = time.time()
+        for r in reqs:
+            t0 = self._t_submit.get(r.rid)
+            if t0 is not None:
+                self.stats.queue_wait_s.append(now - t0)
 
     def _admit_inner(self) -> None:
         freed, filled = self.batcher.admit()
@@ -503,6 +516,7 @@ class ServingLoop:
         if not self.paged and self.bucket_table is None:
             for i in filled:  # legacy exact-length path
                 r = self.batcher.slots[i].request
+                self._prefill_dispatched([r])
                 logits = self.engine.prefill_slots(r.prompt[None, :], [i])
                 self._record_first(r, logits[0])
             return
@@ -528,6 +542,8 @@ class ServingLoop:
                 suffix = r.prompt[pasts[row]:]
                 prompts[row, : len(suffix)] = suffix
                 lengths[row] = len(suffix)
+            self._prefill_dispatched(
+                [self.batcher.slots[i].request for i in slots])
             if self.paged:
                 logits = self.engine.prefill_slots_paged(
                     prompts, slots, lengths, pasts
@@ -556,7 +572,7 @@ class ServingLoop:
         the prompt, and rejoins decode."""
         if not self._prefill_tasks:
             return
-        with self._tr.span("prefill_chunk"):
+        with self._tr.span("serving.prefill_chunk"):
             self._prefill_chunk()
 
     def _prefill_chunk(self) -> None:
@@ -586,6 +602,9 @@ class ServingLoop:
             lengths[row] = n
             pasts[row] = t.done
             slots.append(t.slot)
+        self._prefill_dispatched([t.req for t, _ in rows if not t.started])
+        for t, _ in rows:
+            t.started = True
         logits = self.engine.prefill_slots_paged(prompts, slots, lengths, pasts)
         self.stats.prefill_chunks += 1
         unfinished = []
@@ -604,7 +623,8 @@ class ServingLoop:
 
     def _record_first(self, r: Request, row_logits,
                       slot: Optional[int] = None) -> None:
-        tok = int(np.asarray(jnp.argmax(row_logits, -1)))
+        with self._tr.span("serving.sync"):
+            tok = int(np.asarray(jnp.argmax(row_logits, -1)))
         r.generated.append(tok)
         if self.drafter is not None and slot is not None:
             self.drafter.extend(slot, [tok])
@@ -654,16 +674,16 @@ class ServingLoop:
             self._planned = []
         if self._pending_counts is None:
             return
-        counts = np.asarray(self._pending_counts)
+        counts, dispatch = self._pending_counts
         self._pending_counts = None
-        eng.observe(counts)
+        eng.observe(counts, dispatch)
         self._steps_since_replan += 1
         if self._steps_since_replan < self.policy.replan_every:
             return
         self._steps_since_replan = 0
         st, es = self.stats, eng.stats
         thrash_before = es.thrash_events
-        with self._tr.span("replan", cat="scheduler"):
+        with self._tr.span("scheduler.replan"):
             self._planned = eng.plan_migrations()
         st.replans += 1
         st.migrations += sum(
@@ -680,11 +700,12 @@ class ServingLoop:
         at exact loop iterations; call `finish()` when done.
 
         With tracing enabled (repro.obs) each iteration is one nested
-        span tree: step > {admit, prefill_chunk, decode > {replan,
-        migrate}} plus a per-step slot-occupancy counter track — the
-        "where did this step's time go" view."""
+        span tree: serving.step > {serving.admit, serving.prefill_chunk,
+        serving.decode > {scheduler.replan, scheduler.migrate,
+        serving.sync}} — the "where did this step's time go" view, on
+        the device trace's clock under the JAX profiler."""
         tr = self._tr
-        with tr.span("step"):
+        with tr.span("serving.step"):
             self._admit()
             # piggyback: one budgeted prefill chunk rides along with
             # this iteration's decode step (chunked_prefill)
@@ -692,12 +713,6 @@ class ServingLoop:
             gb = self.batcher.next_group()
             self.stats.util_sum += self.batcher.utilization
             self.stats.util_samples += 1
-            if tr.enabled:
-                tr.counter("loop/slots", {
-                    "utilization": self.batcher.utilization,
-                    "queued": len(self.batcher.queue),
-                    "prefill_tasks": len(self._prefill_tasks),
-                })
             if gb is None:
                 # the active group is idle — use its step slot for any
                 # outstanding migration work instead
@@ -706,10 +721,10 @@ class ServingLoop:
                 return
             _, idxs, toks, pos, live = gb
             if self.spec:
-                with tr.span("decode"):
+                with tr.span("serving.decode"):
                     self._spec_step(idxs, toks, pos, live)
                 return
-            with tr.span("decode"):
+            with tr.span("serving.decode"):
                 if self.paged:
                     for row, i in enumerate(idxs):
                         if live[row]:
@@ -727,8 +742,9 @@ class ServingLoop:
                 # device, the host applies + replans migrations from
                 # previous loads
                 self._flush_replan()
-                self._pending_counts = counts
-                nxt = np.asarray(jnp.argmax(logits, -1))
+                self._pending_counts = (counts, self.engine.last_dispatch)
+                with tr.span("serving.sync"):
+                    nxt = np.asarray(jnp.argmax(logits, -1))
             live_idx = [i for i, alive in zip(idxs, live) if alive]
             self.batcher.record(live_idx, nxt[live])
             self.stats.decode_steps += 1
@@ -760,7 +776,7 @@ class ServingLoop:
         st = self.stats
         tr = self._tr
         drafts: List[List[int]] = [[] for _ in idxs]
-        with tr.span("spec.draft", cat="spec"):
+        with tr.span("spec.draft"):
             for row, i in enumerate(idxs):
                 if not live[row]:
                     continue
@@ -788,14 +804,15 @@ class ServingLoop:
                 # on-demand alloc + COW for every chunk position (the
                 # same contract as plain decode, k+1 positions at once)
                 self.kv.ensure_block(i, p)
-        with tr.span("spec.verify", cat="spec"):
+        with tr.span("spec.verify"):
             logits, counts = self.engine.verify_slots_paged(
                 chunk, idxs, lens, pos, live=live
             )
             # zigzag overlap, exactly like the plain decode step
             self._flush_replan()
-            self._pending_counts = counts
-            nxt = np.asarray(jnp.argmax(logits, -1))  # [W, Kp]
+            self._pending_counts = (counts, self.engine.last_dispatch)
+            with tr.span("serving.sync"):
+                nxt = np.asarray(jnp.argmax(logits, -1))  # [W, Kp]
         st.decode_steps += 1
         if n_drafted:
             st.spec_steps += 1

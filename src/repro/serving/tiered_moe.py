@@ -170,6 +170,15 @@ def init_tiered_state(rng, cfg, sizes: TierSizes, pad_cold_to: int = 16) -> Para
     }
 
 
+def tier_capacity(tid: int, t: int, top_k: int, cold_capacity_frac: float) -> int:
+    """Rows per buffer slot of tier `tid` in a step over `t` tokens: hot
+    and warm serve any skew droplessly; cold buffers run at the
+    invariant-backed reduced capacity."""
+    if tid != COLD_T:
+        return t
+    return max(top_k, int(t * cold_capacity_frac + 0.999))
+
+
 def _tier_ffn(w: jnp.ndarray, h: jnp.ndarray, kind: str = "ref",
               decode: bool = False) -> jnp.ndarray:
     """w: [n, 3, D, F]; h: [n, C, D] -> [n, C, D], routed by the
@@ -243,11 +252,7 @@ def tiered_moe_forward(
     y = jnp.zeros((t, d), x.dtype)
     for tid, key in enumerate(TIER_KEYS):
         n_slots = state[key].shape[0]
-        # hot/warm serve any skew droplessly; cold buffers run at the
-        # invariant-backed reduced capacity
-        cap = t if tid != COLD_T else max(
-            mo.top_k, int(t * cold_capacity_frac + 0.999)
-        )
+        cap = tier_capacity(tid, t, mo.top_k, cold_capacity_frac)
         in_tier = a_tier == tid
         if a_live is not None:
             in_tier = in_tier & a_live

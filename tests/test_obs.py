@@ -140,7 +140,7 @@ def test_wall_s_accumulates_across_runs_and_reset_clears(setup):
 # ------------------------------------------------------------- tracing
 def test_disabled_tracer_is_null_and_empty():
     tr = Tracer(enabled=False)
-    s = tr.span("step", phase=1)
+    s = tr.span("serving.step", phase=1)
     assert s is NULL_SPAN  # shared singleton: no per-call allocation
     with s:
         pass
@@ -176,15 +176,16 @@ def test_trace_export_round_trip(setup, tmp_path):
     assert validate_trace_events(events) == []  # fields + nesting
 
     names = {e["name"] for e in events}
-    for want in ("step", "admit", "decode", "replan"):
+    for want in ("serving.step", "serving.admit", "serving.decode",
+                 "scheduler.replan"):
         assert want in names, f"missing {want} span"
     # spans nest: every decode span lies inside some step span
     spans = {n: [(e["ts"], e["ts"] + e["dur"]) for e in events
                  if e.get("ph") == "X" and e["name"] == n]
-             for n in ("step", "decode")}
-    assert spans["decode"]
-    for s0, s1 in spans["decode"]:
-        assert any(t0 <= s0 and s1 <= t1 for t0, t1 in spans["step"])
+             for n in ("serving.step", "serving.decode")}
+    assert spans["serving.decode"]
+    for s0, s1 in spans["serving.decode"]:
+        assert any(t0 <= s0 and s1 <= t1 for t0, t1 in spans["serving.step"])
 
 
 def test_kernel_spans_on_shared_timeline(setup, tmp_path):
@@ -236,3 +237,164 @@ def test_resolve_obs_precedence(setup):
     from repro.kernels.backend import set_kernel_tracer
 
     set_kernel_tracer(None)  # resolve_obs(cfg_traced) installed one
+
+
+# ------------------------------------ spans on the profiler's clock
+PROGRAM_SPANS = ("serving.step", "serving.admit", "serving.prefill_chunk",
+                 "serving.decode", "serving.sync", "scheduler.replan",
+                 "scheduler.migrate")
+
+
+def _forced_migrations():
+    from repro.core.policy import SchedulerPolicy
+    from repro.core.tiers import TierThresholds
+
+    # smoke-scale loads are tiny: these thresholds plus plan_min=1 make
+    # the planner move experts on every replan
+    return SchedulerPolicy(
+        thresholds=TierThresholds(tau_hot=6.0, tau_cold=1.0), plan_min=1)
+
+
+def _xplane_host_events(trace_dir):
+    """(name, start_ns, end_ns) of every host event in the one profile
+    written under `trace_dir`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def _profiled_steps(loop, cfg, trace_dir, rid0):
+    """Two iterations under the profiler, each with an admission, a
+    prefill chunk, a decode step and a migration."""
+    reqs = _requests(cfg, n=4, new_tokens=8, seed=rid0)
+    for i, r in enumerate(reqs):
+        r.rid = rid0 + i
+    for r in reqs[:2]:
+        loop.submit(r)
+    steps = loop.stats.decode_steps
+    for _ in range(50):  # until the first two decode, migrating
+        if loop.stats.decode_steps >= steps + 2:
+            break
+        loop.step_once()
+    for r in reqs[2:]:
+        loop.submit(r)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(trace_dir), profiler_options=opts):
+        for _ in range(2):
+            loop.step_once()
+    loop.run()
+    return _xplane_host_events(trace_dir)
+
+
+def test_spans_land_in_profiler_trace_only_when_enabled(setup, tmp_path):
+    cfg, params = setup
+    loop = ServingLoop(cfg, params, batch_size=4, n_groups=1, cache_len=24,
+                       prefill_chunk_tokens=2, scheduler=_forced_migrations())
+    _profiled_steps(loop, cfg, tmp_path / "warm", 100)  # compile, untraced
+    loop.obs.tracer.enabled = True
+    events = _profiled_steps(loop, cfg, tmp_path / "on", 200)
+    loop.obs.tracer.enabled = False
+    names = {n for n, _, _ in events}
+    for want in PROGRAM_SPANS:
+        assert want in names, f"{want} missing from the profiler trace"
+    steps = [(s, e) for n, s, e in events if n == "serving.step"]
+    assert len(steps) == 2
+    for name in ("serving.decode", "serving.sync"):
+        inner = [(s, e) for n, s, e in events if n == name]
+        assert inner
+        for s0, s1 in inner:
+            assert any(t0 <= s0 and s1 <= t1 for t0, t1 in steps), name
+
+    off = _profiled_steps(loop, cfg, tmp_path / "off", 300)
+    assert not {n for n, _, _ in off} & set(PROGRAM_SPANS)
+
+
+# ------------------------------------------ counters of the MoE layers
+def _device_tiers(eng):
+    """[L, E] expert_tier tables read back from the device."""
+    rows = []
+    for kind, name, g in eng._layer_keys:
+        if kind == "layer":
+            rows.append(np.asarray(eng.tiered[name]["expert_tier"]))
+        else:
+            rows.append(np.asarray(eng.tiered["stack"][name]["expert_tier"])[g])
+    return np.stack(rows)
+
+
+def test_routed_split_follows_the_layout_each_step_ran_with(setup):
+    cfg, params = setup
+    loop = ServingLoop(cfg, params, batch_size=4, n_groups=1, cache_len=24,
+                       scheduler=_forced_migrations())
+    eng = loop.engine
+    seen = []  # (counts, device tier tables before the step)
+    orig = eng.step_slots_paged
+
+    def step(*a, **kw):
+        tiers = _device_tiers(eng)
+        logits, counts = orig(*a, **kw)
+        seen.append((counts, tiers))
+        return logits, counts
+
+    eng.step_slots_paged = step
+    _serve(loop, _requests(cfg, n=4, new_tokens=6))
+    st = eng.stats
+    assert st.migrations > 0
+    assert len({t.tobytes() for _, t in seen}) > 2  # the layout moved
+    want = np.zeros(3)
+    for counts, tiers in seen:
+        want += np.bincount(tiers.ravel(), weights=np.asarray(counts).ravel(),
+                            minlength=3)
+    assert [st.routed_hot, st.routed_warm, st.routed_cold] == want.tolist()
+    assert st.moe_steps == len(seen) == loop.stats.decode_steps
+    assert st.routed_hot + st.routed_warm + st.routed_cold == st.moe_assignments
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "jamba-v0.1-52b"])
+def test_expert_rows_and_assignments_by_hand_for_one_group(arch):
+    """Also on a hybrid arch, whose expert counts carry a (zero) row for
+    every non-MoE layer."""
+    cfg = reduce_for_smoke(get_config(arch))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    loop = ServingLoop(cfg, params, batch_size=6, n_groups=2, cache_len=24)
+    eng, kv = loop.engine, loop.kv
+    w = 3  # rows of one zigzag group
+    live = np.array([True, False, True])
+    tables = np.full((w, kv.blocks_per_slot), kv.trash, np.int32)
+    _, counts = eng.step_slots_paged(np.ones((w, 1), np.int32),
+                                     np.array([4, 0, 9]), [0, 1, 2], tables,
+                                     live=live)
+    eng.observe(counts, eng.last_dispatch)
+    counts = np.asarray(counts)[eng._moe_rows]
+    n_moe, k = len(eng._layer_keys), cfg.moe.top_k
+    assert n_moe == sum(cfg.uses_moe_layer(i) for i in range(cfg.n_layers))
+    n_hot, n_warm, n_cold = eng.sizes
+    cold_slots = -(-n_cold // 16) * 16  # init_tiered_state pads to 16
+    # every tier slot runs at capacity w (cold: at least top_k)
+    rows = n_moe * ((n_hot + n_warm) * w + cold_slots * max(k, w))
+    st = eng.stats
+    assert st.moe_steps == 1
+    assert st.moe_assignments == 2 * k * n_moe  # two live tokens, k each
+    assert st.expert_rows == rows
+    assert st.experts_touched == int((counts > 0).sum())
+    assert 0 < st.experts_touched <= st.moe_assignments
+
+
+def test_queue_wait_one_sample_per_request(setup):
+    cfg, params = setup
+    # 2-token chunks: each prompt (4-6 tokens) takes several chunk calls
+    loop = ServingLoop(cfg, params, batch_size=2, n_groups=1, cache_len=24,
+                       prefill_chunk_tokens=2)
+    reqs = _requests(cfg, n=5, new_tokens=2)
+    _serve(loop, reqs)
+    waits = loop.stats.queue_wait_s
+    assert len(waits) == len(reqs)
+    assert all(w >= 0 for w in waits)
+    # 5 requests through 2 slots: the later ones waited for a slot
+    assert max(waits) > min(waits)

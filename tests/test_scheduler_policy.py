@@ -110,7 +110,7 @@ from repro.core.tiers import COLD, HOT, WARM  # noqa: E402
 def _layout_tiers(eng):
     """Initial per-(layer, expert) tier placement of the live engine."""
     return np.stack([
-        np.asarray(eng._get_state(k)["expert_tier"]) for k in eng._layer_keys
+        eng._read_tables(k)[0] for k in eng._layer_keys
     ])
 
 

@@ -5,7 +5,8 @@ Runs the serving loop on a smoke-scale MoE config with
 
   * a Chrome/Perfetto-loadable `trace_event` JSON (open it at
     https://ui.perfetto.dev or chrome://tracing) with the nested
-    step/admit/prefill_chunk/decode/replan/migrate spans, the
+    serving.{step,admit,prefill_chunk,decode,sync} and
+    scheduler.{replan,migrate} spans, the
     kernel.<op> compile spans, the tier/{experts,predicted_load}
     counter tracks, and the tier_migration / thrash instants;
   * a metrics snapshot JSON — the loop's `MetricsRegistry.snapshot()`
@@ -38,7 +39,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # span families the exported timeline must carry (acceptance criteria:
 # nested step/prefill/decode/replan spans + tier-migration instants)
-REQUIRED_SPANS = ("step", "prefill_chunk", "decode", "replan")
+REQUIRED_SPANS = ("serving.step", "serving.prefill_chunk", "serving.decode",
+                  "scheduler.replan")
 REQUIRED_INSTANTS = ("tier_migration",)
 
 
