@@ -275,7 +275,7 @@ def tiered_moe_forward(
 
 
 # ------------------------------------------------------------ migrations
-def apply_migrations(state: Params, plan: jnp.ndarray) -> Params:
+def apply_migrations(state: Params, plan: jnp.ndarray, g=None) -> Params:
     """Execute a fixed-size migration plan (padded with no-ops).
 
     plan: [M, 5] int32 rows (expert_a, tier_a, slot_a, tier_b, slot_b):
@@ -284,54 +284,59 @@ def apply_migrations(state: Params, plan: jnp.ndarray) -> Params:
     expert_a < 0 is a no-op. On hardware each swap lowers to resharding
     collectives between differently-sharded buffers — the DIMM-Link
     relayout/rebalance analogue, overlapped with the next step's compute.
+
+    `state` is one layer's tier state, or with `g` a stack of layer
+    groups ([G, n, 3, D, F] buffers, [G, E] tables) of which group `g`
+    (traced) migrates. A swap reads and writes only the slot windows it
+    touches: each tier buffer takes one `dynamic_update_slice` per side
+    of the swap, of the swapped expert or of the slot's own value, so
+    no row branches over whole buffers and, jitted with the state
+    donated, XLA updates the buffers in place. Rows run up to the last
+    live one; no-op rows before it write back what they read.
     """
+    if g is None:
+        one = jax.tree.map(lambda a: a[None], state)
+        return jax.tree.map(lambda a: a[0], apply_migrations(one, plan, 0))
+    bufs = [state[k] for k in TIER_KEYS]
+    window = (1, 1) + bufs[0].shape[2:]
+    zeros = (0,) * (len(window) - 2)
 
-    def one(state, row):
-        ea, ta, sa, tb, sb = row[0], row[1], row[2], row[3], row[4]
+    def start(buf, slot):
+        return (g, jnp.clip(slot, 0, buf.shape[1] - 1)) + zeros
 
-        def do(state):
-            bufs = [state["hot"], state["warm"], state["cold"]]
+    def pick(tid, xs):
+        return jnp.where(tid == 0, xs[0], jnp.where(tid == 1, xs[1], xs[2]))
 
-            def get(tid, slot):
-                return jax.lax.switch(
-                    tid,
-                    [lambda s=s: jax.lax.dynamic_index_in_dim(bufs[s], slot, 0)
-                     for s in range(3)],
-                )
+    def one(i, carry):
+        bufs, tier_g, slot_g = carry
+        ea, ta, sa, tb, sb = plan[i, 0], plan[i, 1], plan[i, 2], plan[i, 3], plan[i, 4]
+        live = ea >= 0
+        at_a = [jax.lax.dynamic_slice(b, start(b, sa), window) for b in bufs]
+        at_b = [jax.lax.dynamic_slice(b, start(b, sb), window) for b in bufs]
+        # every read lands before any write, or XLA copies the buffers
+        bufs, at_a, at_b = jax.lax.optimization_barrier((bufs, at_a, at_b))
+        wa, wb = pick(ta, at_a), pick(tb, at_b)
+        new_bufs = []
+        for tid, buf in enumerate(bufs):
+            xa = jnp.where(live & (ta == tid), wb, at_a[tid])
+            buf = jax.lax.dynamic_update_slice(buf, xa, start(buf, sa))
+            # side b's window, after side a's write if they coincide
+            same = start(buf, sa)[1] == start(buf, sb)[1]
+            xb = jnp.where(live & (tb == tid), wa, jnp.where(same, xa, at_b[tid]))
+            new_bufs.append(jax.lax.dynamic_update_slice(buf, xb, start(buf, sb)))
+        # table update: expert at (tb, sb) before the swap moves to (ta, sa)
+        occupant_b = jnp.argmax((tier_g == tb) & (slot_g == sb)).astype(jnp.int32)
+        tier = tier_g.at[ea].set(tb).at[occupant_b].set(ta)
+        slot = slot_g.at[ea].set(sb).at[occupant_b].set(sa)
+        return (new_bufs, jnp.where(live, tier, tier_g),
+                jnp.where(live, slot, slot_g))
 
-            wa = get(ta, sa)
-            wb = get(tb, sb)
-            new_bufs = []
-            for tid in range(3):
-                buf = bufs[tid]
-                buf = jax.lax.cond(
-                    ta == tid,
-                    lambda b: jax.lax.dynamic_update_index_in_dim(b, wb[0], sa, 0),
-                    lambda b: b,
-                    buf,
-                )
-                buf = jax.lax.cond(
-                    tb == tid,
-                    lambda b: jax.lax.dynamic_update_index_in_dim(b, wa[0], sb, 0),
-                    lambda b: b,
-                    buf,
-                )
-                new_bufs.append(buf)
-            # table update: expert at (tb, sb) before the swap moves to (ta, sa)
-            occupant_b = jnp.argmax(
-                (state["expert_tier"] == tb) & (state["expert_slot"] == sb)
-            ).astype(jnp.int32)
-            tier = state["expert_tier"].at[ea].set(tb).at[occupant_b].set(ta)
-            slot = state["expert_slot"].at[ea].set(sb).at[occupant_b].set(sa)
-            return {
-                "hot": new_bufs[0],
-                "warm": new_bufs[1],
-                "cold": new_bufs[2],
-                "expert_tier": tier,
-                "expert_slot": slot,
-            }
-
-        return jax.lax.cond(ea >= 0, do, lambda s: s, state), None
-
-    state, _ = jax.lax.scan(one, state, plan)
-    return state
+    rows = jnp.arange(1, plan.shape[0] + 1, dtype=jnp.int32)
+    n = jnp.max(jnp.where(plan[:, 0] >= 0, rows, 0))
+    tables = [jax.lax.dynamic_index_in_dim(state[f], g, 0, keepdims=False)
+              for f in ("expert_tier", "expert_slot")]
+    bufs, tier_g, slot_g = jax.lax.fori_loop(0, n, one, (bufs, *tables))
+    out = dict(state, **dict(zip(TIER_KEYS, bufs)))
+    for f, t in (("expert_tier", tier_g), ("expert_slot", slot_g)):
+        out[f] = jax.lax.dynamic_update_index_in_dim(state[f], t, g, 0)
+    return out

@@ -43,7 +43,8 @@ def test_tiered_equals_flat_moe(setup):
     np.testing.assert_array_equal(np.asarray(counts_t), np.asarray(out.expert_counts))
 
 
-def test_migration_preserves_outputs(setup):
+@pytest.mark.parametrize("donated", [False, True], ids=["functional", "in_place"])
+def test_migration_preserves_outputs(setup, donated):
     cfg, p, state, x = setup
     ref, _ = tiered_moe_forward(p, state, cfg, x, cold_capacity_frac=1.0)
     # chain of swaps across all three tiers
@@ -51,7 +52,11 @@ def test_migration_preserves_outputs(setup):
         [[0, 0, 0, 2, 1], [3, 1, 1, 0, 0], [-1, 0, 0, 0, 0], [5, 2, 0, 1, 2]],
         jnp.int32,
     )
-    st2 = apply_migrations(state, plan)
+    if donated:  # the engine's jit: the state's buffers are reused
+        st2 = jax.jit(apply_migrations, donate_argnums=0)(
+            jax.tree.map(jnp.copy, state), plan)
+    else:
+        st2 = apply_migrations(state, plan)
     got, _ = tiered_moe_forward(p, st2, cfg, x, cold_capacity_frac=1.0)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32), atol=1e-2
@@ -139,3 +144,107 @@ def test_token_mask_excludes_dead_tokens_from_counts(setup):
     np.testing.assert_allclose(
         np.asarray(y[:1], np.float32), np.asarray(y_live, np.float32), atol=1e-2
     )
+
+
+# -------------------------------------------------- in-place migrations
+# granite-moe at smoke scale: one MoE slot scanned over 4 groups, 8
+# experts in tiers 2/3/3 (cold padded to 16 slots). Initial layout per
+# group: experts 0-1 hot 0-1, 2-4 warm 0-2, 5-7 cold 0-2. Each plan is
+# a list of (expert, tier, slot, tier, slot) swaps valid in sequence.
+MIGRATION_PLANS = {
+    "no_op": [(-1, 0, 0, 0, 0)],
+    "cross_tier": [(0, 0, 0, 2, 1)],
+    "same_tier": [(2, 1, 0, 1, 2)],
+    "no_op_between": [(3, 1, 1, 0, 0), (-1, 0, 0, 0, 0), (5, 2, 0, 1, 2)],
+    # expert 0 hot -> warm -> cold, then back to hot
+    "chained": [(0, 0, 0, 1, 1), (0, 1, 1, 2, 0), (0, 2, 0, 0, 0)],
+}
+
+
+@pytest.fixture(scope="module")
+def stacked_parts():
+    from repro.models.model import init_params
+    from repro.serving.engine import fill_tiers_from_params, init_tiered_for_model
+
+    cfg = reduce_for_smoke(get_config("granite-moe-1b-a400m"))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    sizes = TierSizes(2, 3, 3)
+    tiered = fill_tiers_from_params(
+        params, init_tiered_for_model(jax.random.PRNGKey(1), cfg, sizes), cfg)
+    return cfg, params, tiered, sizes
+
+
+def _stacked_engine(parts):
+    """A fresh engine over its own copy of the tier state (the engine
+    donates what it is given)."""
+    from repro.serving.engine import TriMoEServingEngine
+    from repro.serving.paged_kv import PagedKVCache
+
+    cfg, params, tiered, sizes = parts
+    kv = PagedKVCache(cfg, 2, 16, prefix_cache=False)
+    return TriMoEServingEngine(cfg, params, kv, jax.tree.map(jnp.copy, tiered),
+                               sizes=sizes)
+
+
+def _numpy_swaps(stack: dict, plan: np.ndarray, g: int) -> dict:
+    """Group g's tier buffers after the weight swaps of `plan`."""
+    out = {k: np.array(stack[k]) for k in ("hot", "warm", "cold")}
+    bufs = [out["hot"], out["warm"], out["cold"]]
+    for ea, ta, sa, tb, sb in plan:
+        if ea >= 0:
+            wa, wb = bufs[ta][g, sa].copy(), bufs[tb][g, sb].copy()
+            bufs[ta][g, sa], bufs[tb][g, sb] = wb, wa
+    return out
+
+
+@pytest.mark.parametrize("g", [0, 1, 3])
+@pytest.mark.parametrize("case", list(MIGRATION_PLANS))
+def test_stacked_migration_swaps_only_its_group_in_place(stacked_parts, case, g):
+    """`apply_planned` on a stacked layer (`engine._migrate_stack`):
+    group g's buffers equal a NumPy swap of the same stack, every other
+    group is bit-identical, the tables equal `_swapped`, and the tier
+    arrays handed in were donated."""
+    from repro.serving.engine import _swapped
+
+    eng = _stacked_engine(stacked_parts)
+    key = ("stack", "slot0", g)
+    plan = np.full((eng.policy.plan_rows, 5), -1, np.int32)
+    plan[: len(MIGRATION_PLANS[case])] = MIGRATION_PLANS[case]
+    before = eng.tiered["stack"]["slot0"]
+    host = jax.tree.map(np.array, before)
+    tables = _swapped(eng._applied, [(eng._layer_index[key], plan)])
+    eng.apply_planned([(key, plan)])
+    after = jax.tree.map(np.asarray, eng.tiered["stack"]["slot0"])
+    want = _numpy_swaps(host, plan, g)
+    others = [h for h in range(host["hot"].shape[0]) if h != g]
+    for k in ("hot", "warm", "cold"):
+        np.testing.assert_array_equal(after[k], want[k], err_msg=k)
+        np.testing.assert_array_equal(after[k][others], host[k][others])
+    for k in ("expert_tier", "expert_slot"):
+        np.testing.assert_array_equal(after[k][others], host[k][others])
+    li = eng._layer_index[key]
+    np.testing.assert_array_equal(after["expert_tier"][g], tables[0][li])
+    np.testing.assert_array_equal(after["expert_slot"][g], tables[1][li])
+    np.testing.assert_array_equal(eng._applied[0], tables[0])
+    np.testing.assert_array_equal(eng._applied[1], tables[1])
+    assert all(a.is_deleted() for a in jax.tree.leaves(before))
+
+
+@pytest.mark.parametrize("kind", ["stack", "layer"])
+def test_migrations_donate_the_tier_buffers(stacked_parts, kind):
+    """Both migration programs alias their tier state input to output,
+    so a swap never writes a fresh copy of the buffers: the arrays
+    passed in are deleted, and a plan of no-ops keeps every value."""
+    eng = _stacked_engine(stacked_parts)
+    stack = eng.tiered["stack"]["slot0"]
+    noop = jnp.full((eng.policy.plan_rows, 5), -1, jnp.int32)
+    if kind == "stack":
+        state, migrate, args = stack, eng._migrate_stack, (noop, 2)
+    else:  # an unrolled layer's state: one group of the stack
+        state = jax.tree.map(lambda a: a[2], stack)
+        migrate, args = eng._migrate, (noop,)
+    host = jax.tree.map(np.array, state)
+    out = migrate(state, *args)
+    assert all(a.is_deleted() for a in jax.tree.leaves(state))
+    for k in host:
+        np.testing.assert_array_equal(np.asarray(out[k]), host[k], err_msg=k)

@@ -7,12 +7,15 @@ interpret-mode tests cannot show. Shapes are granite-moe-1b-a400m's
 published widths and the serving loop's defaults: Kv=8 heads of 64 with
 G=2 query heads each, 4-token KV blocks, 4-row prefill chunks of 32
 tokens, decode groups of 4 rows, d_model 1024 and experts of width 512.
+The expert migrations compile at the benchmark's tier split: 24 layers
+of 8 hot, 10 warm and 16 cold slots.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.expert_gemv.expert_gemv import expert_ffn_gemv
 from repro.kernels.moe_gemm.moe_gemm import moe_gemm
 from repro.kernels.paged_attention import paged_prefill_gqa, paged_prefill_mla
+from repro.serving.tiered_moe import apply_migrations
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 KV, G, HD, BS = 8, 2, 64, 4  # granite kv heads, group, head dim; loop block
@@ -111,3 +115,21 @@ def test_expert_ffn_gemv_compiles(s, tokens):
         s((F, D), BF16), interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("stacked", [True, False], ids=["stack", "layer"])
+def test_migrations_update_tier_buffers_in_place(s, stacked):
+    """The engine's migration programs (tier state donated) compile to
+    updates of the swapped slots: no op copies a whole tier buffer."""
+    lead = (24,) if stacked else ()
+    state = {k: s(lead + (n, 3, D, F), BF16)
+             for k, n in (("hot", 8), ("warm", 10), ("cold", 16))}
+    state.update({k: s(lead + (32,), I32) for k in ("expert_tier", "expert_slot")})
+    args = (state, s((8, 5), I32)) + ((s((), I32),) if stacked else ())
+    hlo = jax.jit(apply_migrations, donate_argnums=0).lower(*args).compile().as_text()
+    assert "input_output_alias" in hlo
+    bufs = {f"bf16[{','.join(map(str, (lead or (1,)) + (n, 3, D, F)))}]"
+            for n in (8, 10, 16)} | {f"bf16[{n},3,{D},{F}]" for n in (8, 10, 16)}
+    outs = [m.group(1) for m in re.finditer(r"= (.*?) copy(?:-start)?\(", hlo)]
+    copies = [o for o in outs if any(b in o for b in bufs)]
+    assert not copies, copies[:3]
