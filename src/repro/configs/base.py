@@ -31,6 +31,27 @@ class MoEConfig:
     # serving tier sizing (TriMoE): slots per tier; scheduler fills them.
     n_hot_slots: int = 0  # 0 => n_shared + max(1, n_experts // 16)
     n_warm_frac: float = 0.30  # paper §3.1: ~30% warm
+    # router: "softmax" (probabilities, top-k) or "sigmoid" (independent
+    # scores; GLM-4.5 / DeepSeek-V3). `selection_bias` adds a learned
+    # per-expert bias to the scores for choosing the top-k only; the
+    # gates stay the unbiased scores of the chosen experts, divided by
+    # their sum.
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    # expert share (expert parallelism): the router has `n_routed`
+    # outputs (0 => n_experts) and this chip holds the `n_experts`
+    # experts with global ids [expert_offset, expert_offset + n_experts)
+    n_routed: int = 0
+    expert_offset: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
+
+    @property
+    def is_share(self) -> bool:
+        """Whether some routed experts are held on other chips."""
+        return self.router_width != self.n_experts
 
 
 @dataclass(frozen=True)
@@ -88,6 +109,9 @@ class ModelConfig:
     # hybrid (jamba): attention every `attn_every` layers, Mamba otherwise
     attn_every: int = 0  # 0 => all layers attention
     rope_theta: float = 1e4
+    # share of each head's dims that rotary embeddings rotate (the
+    # first `rotary_frac * head_dim`; the rest pass through unrotated)
+    rotary_frac: float = 1.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     qkv_bias: bool = False  # qwen2.5
@@ -194,7 +218,7 @@ class ModelConfig:
                 mo = self.moe
                 per_exp = 3 * d * mo.d_expert
                 total += (mo.n_experts + mo.n_shared) * per_exp
-                total += d * mo.n_experts  # router
+                total += d * mo.router_width  # router
                 if self.name.startswith("deepseek"):
                     pass
             else:

@@ -9,6 +9,12 @@ h = silu(x W1_f) * (x W3_f) for the resident token block, and accumulates
 h @ W2_f into a VMEM fp32 accumulator. Weights are read from HBM exactly
 once (bandwidth-optimal — the cold-expert regime is weight-read bound),
 activations stay resident (the 256 KB buffer analogue).
+
+The F tile (`f_tile`) is the widest lane-aligned divisor of F whose
+double-buffered panels fit the scoped VMEM limit beside the token
+block, so no width needs padding: granite's D=1024, F=512 streams one
+512-wide panel per expert, GLM-4.5's D=4096, F=1408 (11 x 128) eleven
+128-wide ones.
 """
 from __future__ import annotations
 
@@ -18,6 +24,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+LANE = 128
+# the TPU compiler's default scoped VMEM limit on v5e (and v4/v6e)
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def f_tile(t: int, d: int, f: int, itemsize: int) -> int:
+    """F tile of the GEMV for `t` token rows at widths D, F: the largest
+    multiple of 128 dividing F whose double-buffered [D, bf] W1 and W3
+    and [bf, D] W2 panels fit the scoped VMEM limit together with the
+    double-buffered token and output blocks and the fp32 accumulator.
+    F itself when no multiple of 128 divides it (a block spanning the
+    whole axis is always legal); the narrowest candidate when none fits,
+    for the compiler to refuse."""
+    fixed = 4 * t * d * itemsize + 4 * t * d  # x, out (2 buffers each), acc
+    cands = [b for b in range(LANE, f + 1, LANE) if f % b == 0] or [f]
+    fits = [b for b in cands if fixed + 2 * 3 * d * b * itemsize <= VMEM_LIMIT_BYTES]
+    return max(fits) if fits else min(cands)
 
 
 def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref):
@@ -45,12 +69,12 @@ def expert_ffn_gemv(
     w3: jnp.ndarray,  # [D, F]
     w2: jnp.ndarray,  # [F, D]
     *,
-    bf: int = 512,
+    bf: int | None = None,  # F tile; None = `f_tile`
     interpret: bool = False,
 ) -> jnp.ndarray:
     t, d = x.shape
     f = w1.shape[1]
-    bf = min(bf, f)
+    bf = bf or f_tile(t, d, f, w1.dtype.itemsize)
     assert f % bf == 0, (f, bf)
     grid = (f // bf,)
 

@@ -26,7 +26,7 @@ def cold_expert_ffn(
     w3: jnp.ndarray,  # [E, D, F]
     w2: jnp.ndarray,  # [E, F, D]
     *,
-    bf: int = 512,
+    bf: Optional[int] = None,
     backend: Optional[str] = None,
     interpret: Optional[bool] = None,  # deprecated: use backend=
     use_ref: Optional[bool] = None,  # deprecated: use backend=
@@ -34,21 +34,14 @@ def cold_expert_ffn(
     """Each expert's buffer runs the fused single-pass FFN — the
     per-DIMM-NDP parallelism of the paper (one localized expert per unit).
 
-    F is zero-padded up to a multiple of the F-tile when it does not
-    divide (exact: silu(0) * 0 = 0 through zero-padded down rows), so
-    any expert width works, not just bf-aligned ones."""
+    `bf` (a divisor of F) overrides the kernel's F tile, which by
+    default divides F (`expert_gemv.f_tile`): the weights go to the
+    kernel as they are, never padded or copied."""
     kind, interp = resolve_op_backend(
         backend, interpret=interpret, use_ref=use_ref, op="cold_expert_ffn"
     )
     with kernel_span("cold_expert_ffn", KernelBackend(kind, interp)):
         if kind == "ref":
             return jax.vmap(expert_ffn_ref)(x, w1, w3, w2)
-        f = w1.shape[-1]
-        bf_eff = min(bf, f)
-        if f % bf_eff:
-            f_pad = (f + bf_eff - 1) // bf_eff * bf_eff
-            w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, f_pad - f)))
-            w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, f_pad - f)))
-            w2 = jnp.pad(w2, ((0, 0), (0, f_pad - f), (0, 0)))
         fn = functools.partial(expert_ffn_gemv, bf=bf, interpret=interp)
         return jax.vmap(fn)(x, w1, w3, w2)

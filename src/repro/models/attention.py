@@ -197,8 +197,8 @@ def gqa_forward(p: Params, cfg, x, positions, *, kv_override=None, causal=True,
         v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
         if "bq" in p:
             q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_frac)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_frac)
     else:
         k, v = kv_override
         if "bq" in p:
@@ -225,8 +225,8 @@ def gqa_decode(p: Params, cfg, x, cache_k, cache_v, pos):
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    q = apply_rope(q, posv, cfg.rope_theta, cfg.rotary_frac)
+    k = apply_rope(k, posv, cfg.rope_theta, cfg.rotary_frac)
     slot = jnp.mod(pos, s_max)
     rows = jnp.arange(b)
     cache_k = cache_k.at[rows, slot].set(k[:, 0])
@@ -412,8 +412,8 @@ def gqa_decode_paged(p: Params, cfg, x, pool_k, pool_v, tables, pos,
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    q = apply_rope(q, posv, cfg.rope_theta, cfg.rotary_frac)
+    k = apply_rope(k, posv, cfg.rope_theta, cfg.rotary_frac)
     pool_k = _paged_write(pool_k, tables, pos, k[:, 0])
     pool_v = _paged_write(pool_v, tables, pos, v[:, 0])
     kind, interpret = _paged_backend(cfg, backend)
@@ -524,8 +524,8 @@ def gqa_prefill_paged(p: Params, cfg, x, pool_k, pool_v, tables, past_len,
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_frac)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_frac)
     pool_k = paged_scatter(pool_k, tables, positions, mask, k)
     pool_v = paged_scatter(pool_v, tables, positions, mask, v)
     lengths = mask.sum(-1).astype(jnp.int32)

@@ -45,8 +45,17 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: [..., seq, n_heads, head_dim]; positions: [..., seq]."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               frac: float = 1.0) -> jnp.ndarray:
+    """x: [..., seq, n_heads, head_dim]; positions: [..., seq].
+
+    Rotate-half over the first `frac * head_dim` dims of each head, with
+    frequencies over those dims; the rest pass through unrotated
+    (partial rotary, GLM-4.5's `partial_rotary_factor`)."""
+    rot = int(round(x.shape[-1] * frac))
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1)
     head_dim = x.shape[-1]
     freqs = rope_frequencies(head_dim, theta)  # [hd/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, hd/2]

@@ -238,7 +238,7 @@ def apply_layer(
     are the UPDATED POOLS.
     """
     mixer, ffn = sig
-    e = cfg.moe.n_experts if cfg.moe is not None else 1
+    e = cfg.moe.router_width if cfg.moe is not None else 1
     aux = jnp.zeros((), jnp.float32)
     counts = jnp.zeros((e,), jnp.int32)
     new_cache: Params = {}
@@ -623,7 +623,7 @@ def decode_step(
     )
     cache = {**cache, "stack": stack_cache}
     logits = _logits(params, cfg, x)[:, 0]
-    e = cfg.moe.n_experts if cfg.moe is not None else 1
+    e = cfg.moe.router_width if cfg.moe is not None else 1
     counts = counts.reshape(-1, e)
     if counts_all:
         counts = jnp.concatenate([jnp.stack(counts_all), counts], axis=0)
@@ -635,6 +635,33 @@ def decode_step(
 # under the paged layout; everything else (recurrent state, cross K/V)
 # stays per-slot (serving/paged_kv.py).
 SEQ_CACHE_KEYS = frozenset({"k", "v", "ckv", "krope"})
+
+
+def _scan_pool_groups(body, x, pools, xs, n_groups: int):
+    """`lax.scan` over the stacked scan groups with the stacked KV pools
+    in the carry. `body(x, group_pools, group_xs)` returns (x, the
+    group's new pools, outputs). Group g's pools are read by index and
+    written back in place; as a scan output instead, the new pools would
+    be a fresh buffer, which costs a whole-pool copy when the caller
+    donates the pools."""
+
+    def step(carry, inp):
+        x, pools = carry
+        g, group_xs = inp
+        mine = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False), pools
+        )
+        x, new, ys = body(x, mine, group_xs)
+        pools = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, g, 0),
+            pools, new,
+        )
+        return (x, pools), ys
+
+    (x, pools), ys = jax.lax.scan(
+        step, (x, pools), (jnp.arange(n_groups, dtype=jnp.int32), xs)
+    )
+    return x, pools, ys
 
 
 def decode_step_paged(
@@ -689,9 +716,8 @@ def decode_step_paged(
 
     tiered_stack = tiered.get("stack") if tiered else None
 
-    def body(carry, inp):
-        x = carry
-        p, pool_c, state_c, ts_stack = inp
+    def body(x, pool_c, inp):
+        p, state_c, ts_stack = inp
         np_, ns_ = {}, {}
         cnts = []
         for j, sig in enumerate(period):
@@ -711,16 +737,16 @@ def decode_step_paged(
                 **{k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS},
             }
             cnts.append(counts)
-        return x, (np_, ns_, jnp.stack(cnts))
+        return x, np_, (ns_, jnp.stack(cnts))
 
-    x, (stack_pools, stack_states, counts) = jax.lax.scan(
-        body, x,
-        (params["stack"], pools["stack"], states["stack"], tiered_stack or {}),
+    x, stack_pools, (stack_states, counts) = _scan_pool_groups(
+        body, x, pools["stack"],
+        (params["stack"], states["stack"], tiered_stack or {}), n_groups,
     )
     new_pools["stack"] = stack_pools
     new_states["stack"] = stack_states
     logits = _logits(params, cfg, x)[:, 0]
-    e = cfg.moe.n_experts if cfg.moe is not None else 1
+    e = cfg.moe.router_width if cfg.moe is not None else 1
     counts = counts.reshape(-1, e)
     if counts_all:
         counts = jnp.concatenate([jnp.stack(counts_all), counts], axis=0)
@@ -798,8 +824,8 @@ def prefill_paged(
 
     tiered_stack = tiered.get("stack") if tiered else None
 
-    def body(x, inp):
-        p, pool_c, ts_stack = inp
+    def body(x, pool_c, inp):
+        p, ts_stack = inp
         np_, ns_ = {}, {}
         for j, sig in enumerate(period):
             ts = ts_stack.get(f"slot{j}") if ts_stack else None
@@ -808,10 +834,11 @@ def prefill_paged(
             )
             np_[f"slot{j}"] = npool
             ns_[f"slot{j}"] = nstate
-        return x, (np_, ns_)
+        return x, np_, ns_
 
-    x, (stack_pools, stack_states) = jax.lax.scan(
-        body, x, (params["stack"], pools["stack"], tiered_stack or {})
+    x, stack_pools, stack_states = _scan_pool_groups(
+        body, x, pools["stack"], (params["stack"], tiered_stack or {}),
+        n_groups,
     )
     new_pools["stack"] = stack_pools
     new_states["stack"] = stack_states
@@ -885,8 +912,8 @@ def decode_verify(
 
     tiered_stack = tiered.get("stack") if tiered else None
 
-    def body(x, inp):
-        p, pool_c, ts_stack = inp
+    def body(x, pool_c, inp):
+        p, ts_stack = inp
         np_, ns_ = {}, {}
         cnts = []
         for j, sig in enumerate(period):
@@ -897,15 +924,16 @@ def decode_verify(
             np_[f"slot{j}"] = npool
             ns_[f"slot{j}"] = nstate
             cnts.append(counts)
-        return x, (np_, ns_, jnp.stack(cnts))
+        return x, np_, (ns_, jnp.stack(cnts))
 
-    x, (stack_pools, stack_states, counts) = jax.lax.scan(
-        body, x, (params["stack"], pools["stack"], tiered_stack or {})
+    x, stack_pools, (stack_states, counts) = _scan_pool_groups(
+        body, x, pools["stack"], (params["stack"], tiered_stack or {}),
+        n_groups,
     )
     new_pools["stack"] = stack_pools
     new_states["stack"] = stack_states
     logits = _logits(params, cfg, x)
-    e = cfg.moe.n_experts if cfg.moe is not None else 1
+    e = cfg.moe.router_width if cfg.moe is not None else 1
     counts = counts.reshape(-1, e)
     if counts_all:
         counts = jnp.concatenate([jnp.stack(counts_all), counts], axis=0)

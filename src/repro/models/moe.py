@@ -34,7 +34,7 @@ from repro.models.layers import Params, dense_init
 class MoEOutput(NamedTuple):
     y: jnp.ndarray  # [B, S, D]
     aux_loss: jnp.ndarray  # scalar load-balance loss
-    expert_counts: jnp.ndarray  # [E] int32 tokens routed per expert
+    expert_counts: jnp.ndarray  # [router_width] int32 tokens per expert
 
 
 # --- sharding hints for the grouped dispatch path (§Perf) -------------
@@ -59,16 +59,20 @@ def _hint(arr, *spec):
 
 
 def init_moe(rng, cfg) -> Params:
+    """Router over all `router_width` experts; weights of the `n_experts`
+    held here (a chip's share under expert parallelism, else all)."""
     mo = cfg.moe
     d, f, e = cfg.d_model, mo.d_expert, mo.n_experts
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(rng, 5)
     p = {
-        "router": dense_init(ks[0], (d, e), jnp.float32),
+        "router": dense_init(ks[0], (d, mo.router_width), jnp.float32),
         "w_gate": dense_init(ks[1], (e, d, f), dt),
         "w_up": dense_init(ks[2], (e, d, f), dt),
         "w_down": dense_init(ks[3], (e, f, d), dt),
     }
+    if mo.selection_bias:  # learned; zero at init, as in GLM-4.5
+        p["router_bias"] = jnp.zeros((mo.router_width,), jnp.float32)
     if mo.n_shared:
         sk = jax.random.split(ks[4], 3)
         p["shared"] = {
@@ -79,12 +83,48 @@ def init_moe(rng, cfg) -> Params:
     return p
 
 
-def router_topk(logits: jnp.ndarray, k: int):
-    """Softmax-then-topk with renormalized weights (DeepSeek-style)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(probs, k)
+def router_topk(logits: jnp.ndarray, mo, bias=None):
+    """Scores, gates and chosen experts of float32 router logits [T, E].
+
+    softmax: top-k of the probabilities (DeepSeek-V2, granite); sigmoid:
+    independent scores (GLM-4.5). A selection `bias` [E] moves which
+    experts are chosen, never the gates: those are the unbiased scores
+    of the chosen experts, divided by their sum. Returns (scores [T, E],
+    gates [T, k], global expert ids [T, k])."""
+    logits = logits.astype(jnp.float32)
+    if mo.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif mo.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {mo.scoring!r}")
+    if bias is None:
+        w, idx = jax.lax.top_k(scores, mo.top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias, mo.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
     w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-    return probs, w, idx
+    return scores, w, idx
+
+
+def route(p: Params, mo, x: jnp.ndarray):
+    """`router_topk` of tokens x [..., D] through the layer's router
+    [D, router_width] (and selection bias, when the layer has one)."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"])
+    return router_topk(logits, mo, p.get("router_bias"))
+
+
+def dispatch_key(a_exp: jnp.ndarray, mo, live=None) -> jnp.ndarray:
+    """Sort key of (token, expert) pairs with global expert ids `a_exp`:
+    the held expert's local id, or `n_experts` (sorts past every held
+    expert and is never dispatched) for an expert held on another chip
+    or, where `live` is False, a pad token."""
+    e = mo.n_experts
+    key = a_exp
+    if mo.is_share:
+        key = a_exp - mo.expert_offset
+        key = jnp.where((key >= 0) & (key < e), key, e)
+    return key if live is None else jnp.where(live, key, e)
 
 
 def moe_backend(cfg, backend: str | None = None):
@@ -191,20 +231,16 @@ def _moe_forward_global(p, cfg, x, capacity_factor, full_capacity,
         cap = min(t, max(k, int(t * k * cf / e + 0.5)))
 
     flat = x.reshape(t, d)
-    logits = jnp.einsum("td,de->te", flat.astype(jnp.float32), p["router"])
-    probs, w, idx = router_topk(logits, k)
+    probs, w, idx = route(p, mo, flat)
     live = None if token_mask is None else token_mask.reshape(t)
 
     # --- flatten (token, expert) assignments and sort by expert ---
     a_tok = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
     a_exp = idx.reshape(-1).astype(jnp.int32)
     a_w = w.reshape(-1)
-    if live is None:
-        a_key = a_exp
-    else:
-        # pad assignments get a sentinel expert id e: they sort past every
-        # real assignment, so they can never claim capacity from one
-        a_key = jnp.where(jnp.repeat(live, k), a_exp, e)
+    # pad assignments and experts held elsewhere get the sentinel key e:
+    # they sort past every held expert, so they never claim capacity
+    a_key = dispatch_key(a_exp, mo, None if live is None else jnp.repeat(live, k))
     order = jnp.argsort(a_key, stable=True)
     se, st, sw = a_key[order], a_tok[order], a_w[order]
     # rank within expert group (se is sorted)
@@ -230,19 +266,21 @@ def _moe_forward_global(p, cfg, x, capacity_factor, full_capacity,
     if mo.n_shared:
         y = y + shared_ffn(p["shared"], x)
 
-    # --- load-balance aux loss (Switch-style) + expert load counts ---
+    # --- load-balance aux loss (Switch-style) + expert load counts,
+    # over every router output
+    er = mo.router_width
     if live is None:
-        counts = jnp.zeros((e,), jnp.int32).at[a_exp].add(1)
+        counts = jnp.zeros((er,), jnp.int32).at[a_exp].add(1)
         frac_tokens = counts.astype(jnp.float32) / (t * k)
         frac_probs = probs.mean(0)
     else:
-        counts = jnp.zeros((e,), jnp.int32).at[a_exp].add(
+        counts = jnp.zeros((er,), jnp.int32).at[a_exp].add(
             jnp.repeat(live, k).astype(jnp.int32)
         )
         n_live = jnp.maximum(live.sum().astype(jnp.float32), 1.0)
         frac_tokens = counts.astype(jnp.float32) / (n_live * k)
         frac_probs = (probs * live[:, None]).sum(0) / n_live
-    aux = mo.router_aux_coef * e * jnp.sum(frac_tokens * frac_probs)
+    aux = mo.router_aux_coef * er * jnp.sum(frac_tokens * frac_probs)
     return MoEOutput(y, aux, counts)
 
 
@@ -269,8 +307,7 @@ def _moe_forward_grouped(p, cfg, x, capacity_factor, full_capacity=False,
     # NOTE (§Perf, refuted iteration): forcing x to data-only sharding here
     # replicates activations across the model axis every MoE layer and its
     # gradient all-reduces cost 18x more collective time than it saves.
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
-    probs, w, idx = router_topk(logits, k)  # [B,S,k]
+    probs, w, idx = route(p, mo, x)  # [B,S,k]
 
     a_tok = jnp.broadcast_to(
         jnp.arange(s, dtype=jnp.int32)[None, :, None], (b, s, k)
@@ -278,7 +315,7 @@ def _moe_forward_grouped(p, cfg, x, capacity_factor, full_capacity=False,
     a_exp = idx.reshape(b, s * k).astype(jnp.int32)
     a_w = w.reshape(b, s * k)
     live = None if token_mask is None else jnp.repeat(token_mask, k, axis=-1)
-    a_key = a_exp if live is None else jnp.where(live, a_exp, e)
+    a_key = dispatch_key(a_exp, mo, live)
 
     order = jnp.argsort(a_key, axis=-1, stable=True)  # row-local sort
     se = jnp.take_along_axis(a_key, order, axis=-1)
@@ -333,12 +370,13 @@ def _moe_forward_grouped(p, cfg, x, capacity_factor, full_capacity=False,
     if mo.n_shared:
         y = y + shared_ffn(p["shared"], x)
 
+    er = mo.router_width
     if live is None:
-        counts = jnp.zeros((e,), jnp.int32).at[a_exp.reshape(-1)].add(1)
+        counts = jnp.zeros((er,), jnp.int32).at[a_exp.reshape(-1)].add(1)
         frac_tokens = counts.astype(jnp.float32) / (b * s * k)
-        frac_probs = probs.reshape(-1, e).mean(0)
+        frac_probs = probs.reshape(-1, er).mean(0)
     else:
-        counts = jnp.zeros((e,), jnp.int32).at[a_exp.reshape(-1)].add(
+        counts = jnp.zeros((er,), jnp.int32).at[a_exp.reshape(-1)].add(
             live.reshape(-1).astype(jnp.int32)
         )
         n_live = jnp.maximum(
@@ -346,7 +384,7 @@ def _moe_forward_grouped(p, cfg, x, capacity_factor, full_capacity=False,
         )
         frac_tokens = counts.astype(jnp.float32) / (n_live * k)
         frac_probs = (
-            probs.reshape(-1, e) * token_mask.reshape(-1)[:, None]
+            probs.reshape(-1, er) * token_mask.reshape(-1)[:, None]
         ).sum(0) / n_live
-    aux = mo.router_aux_coef * e * jnp.sum(frac_tokens * frac_probs)
+    aux = mo.router_aux_coef * er * jnp.sum(frac_tokens * frac_probs)
     return MoEOutput(y, aux, counts)

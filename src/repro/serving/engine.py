@@ -52,6 +52,7 @@ from repro.obs.metrics import RegistryStats
 from repro.serving.kv_cache import SlotKVCache, gather_slots, scatter_slots
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.tiered_moe import (
+    AWAY_T,
     TIER_KEYS,
     TierSizes,
     apply_migrations,
@@ -105,8 +106,8 @@ def fill_tiers_from_params(params: Params, tiered: Params, cfg: ModelConfig) -> 
         slot = np.asarray(state["expert_slot"])
         for tid, key in enumerate(("hot", "warm", "cold")):
             buf = np.asarray(state[key]).copy()
-            for e in np.nonzero(tier == tid)[0]:
-                buf[slot[e]] = np.asarray(wstack[e])
+            for e in np.nonzero(tier == tid)[0]:  # global ids of held experts
+                buf[slot[e]] = np.asarray(wstack[e - cfg.moe.expert_offset])
             new[key] = jnp.asarray(buf)
         return new
 
@@ -169,13 +170,16 @@ class EngineStats(RegistryStats):
             "events", "tier flip-flops within policy.thrash_window"),
         # --- MoE dispatch, from the [L, E] expert counts `observe` gets
         "moe_steps": ("steps", "steps whose expert counts were observed"),
-        "moe_assignments": ("pairs", "live (token, expert) pairs routed"),
-        "experts_touched": ("cells", "(layer, expert) cells routed to"),
+        "moe_assignments": (
+            "pairs", "live (token, expert) pairs routed to held experts"),
+        "experts_touched": ("cells", "(layer, held expert) cells routed to"),
         "expert_rows": (
             "rows", "rows the tier FFNs computed: slots x capacity"),
         "routed_hot": ("pairs", "routed pairs the hot tier served"),
         "routed_warm": ("pairs", "routed pairs the warm tier served"),
         "routed_cold": ("pairs", "routed pairs the cold tier served"),
+        "routed_elsewhere": (
+            "pairs", "routed pairs whose expert is held on another chip"),
     }
     HISTS = {
         "plan_latency_s": ("s", "host-side plan_migrations latency"),
@@ -207,7 +211,9 @@ class TriMoEServingEngine:
     swap experts in place, so once `apply_planned` has run, the arrays
     the caller handed in are deleted and `self.tiered` holds their
     successors. A caller that reads its own tier arrays afterwards
-    passes the engine a copy.
+    passes the engine a copy. Likewise the paged steps donate the KV
+    pools: after a paged decode, prefill or verify, `kv.pools` holds the
+    only live pool arrays.
     """
 
     def __init__(
@@ -302,7 +308,10 @@ class TriMoEServingEngine:
 
         self._prefill_masked = jax.jit(prefill_masked, static_argnums=(4,))
 
-        # --- paged-KV variants: decode/prefill against the block pools
+        # --- paged-KV variants: decode/prefill against the block pools.
+        # The pools are donated: each call writes its tokens' K/V into
+        # the caller's pool buffers in place, so one pool is live however
+        # many steps are in flight (kv.pools holds the successor).
         def step_paged(p, t, pools, states, tables, idx, pos, ts, live):
             sub = gather_slots(states, idx)
             logits, new_pools, new_sub, counts = decode_step_paged(
@@ -311,7 +320,7 @@ class TriMoEServingEngine:
             )
             return logits, new_pools, scatter_slots(states, new_sub, idx), counts
 
-        self._step_paged = jax.jit(step_paged)
+        self._step_paged = jax.jit(step_paged, donate_argnums=2)
 
         def prefill_paged_fn(p, toks, lens, past, tables, pools, ts):
             mask = jnp.arange(toks.shape[1])[None, :] < lens[:, None]
@@ -320,7 +329,7 @@ class TriMoEServingEngine:
                 tiered=ts, cold_capacity_frac=cold_capacity_frac,
             )
 
-        self._prefill_paged = jax.jit(prefill_paged_fn)
+        self._prefill_paged = jax.jit(prefill_paged_fn, donate_argnums=5)
 
         # speculative verify: chunk-of-k through the SAME chunked paged
         # kernels, but keeping every chunk position's logits + the
@@ -332,7 +341,7 @@ class TriMoEServingEngine:
                 tiered=ts, cold_capacity_frac=cold_capacity_frac,
             )
 
-        self._verify_paged = jax.jit(verify_paged_fn)
+        self._verify_paged = jax.jit(verify_paged_fn, donate_argnums=5)
         self.prefill_rows = prefill_rows
         self.decode_table_widths = set()  # distinct sliced widths (pow2)
         self.prefill_table_widths = set()  # paged prefill's sliced widths
@@ -370,8 +379,12 @@ class TriMoEServingEngine:
         # device->host sync. plan_migrations mutates it in lockstep with
         # the swaps it emits, ahead of the device (the
         # apply-before-next-plan assertion keeps the two from diverging).
+        # It covers the held experts only (local ids; global = local +
+        # expert_offset), as do the predictor and the planner.
+        self._held = slice(cfg.moe.expert_offset,
+                           cfg.moe.expert_offset + cfg.moe.n_experts)
         self._host_layout: Dict[int, tuple] = {
-            li: (tiers[li].copy(), slots[li].copy())
+            li: (tiers[li][self._held].copy(), slots[li][self._held].copy())
             for li in range(len(self._layer_keys))
         }
 
@@ -788,27 +801,32 @@ class TriMoEServingEngine:
         With the `dispatch` record of the step that produced `counts`
         (`last_dispatch`, read right after the step), also count what
         the step's MoE layers did (`engine.moe_*`, `expert_rows`,
-        `routed_*`), on the host from the counts alone."""
+        `routed_*`), on the host from the counts alone. Counts have one
+        column per router output; the predictor and the dispatch
+        counters see the held experts' columns, and pairs of experts
+        held on other chips count as `routed_elsewhere`."""
         counts = np.asarray(counts)
         if counts.shape[0] != len(self._layer_keys):  # one row per layer
             counts = counts[self._moe_rows]
+        held = counts[:, self._held]
         for li in range(len(self._layer_keys)):
-            self.predictor.update(li, counts[li])
+            self.predictor.update(li, held[li])
         if dispatch is None:
             return
         st, top_k = self.stats, self.cfg.moe.top_k
         st.moe_steps += 1
-        st.moe_assignments += int(counts.sum())
-        st.experts_touched += int(np.count_nonzero(counts))
+        st.moe_assignments += int(held.sum())
+        st.experts_touched += int(np.count_nonzero(held))
         st.expert_rows += sum(
             n * tier_capacity(tid, dispatch.tokens, top_k, self.cold_capacity_frac)
             for tid, n in enumerate(self._tier_slots)
         )
         by_tier = np.bincount(dispatch.tier.ravel(), weights=counts.ravel(),
-                              minlength=3)
+                              minlength=AWAY_T + 1)
         st.routed_hot += int(by_tier[HOT])
         st.routed_warm += int(by_tier[WARM])
         st.routed_cold += int(by_tier[COLD])
+        st.routed_elsewhere += int(by_tier[AWAY_T])
 
     def plan_migrations(self) -> list:
         """Draw migration plans from the predictor's hysteresis tier
@@ -920,7 +938,8 @@ class TriMoEServingEngine:
                 victim = in_dst[np.argmin(ema[in_dst])]
                 e_tier, e_slot = int(cur_tier[e]), int(cur_slot[e])
                 v_slot = int(cur_slot[victim])
-                plan[emitted] = (e, e_tier, e_slot, dst_tier, v_slot)
+                plan[emitted] = (e + self._held.start, e_tier, e_slot,
+                                 dst_tier, v_slot)  # tables: global ids
                 emitted += 1
                 # maintain the host mirror (swap)
                 cur_tier[victim], cur_slot[victim] = e_tier, e_slot

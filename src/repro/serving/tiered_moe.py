@@ -15,6 +15,13 @@ paper's three compute domains:
 Routing tables (expert_tier[E], expert_slot[E]) are step inputs produced
 by the host-side scheduler; migrations between steps move experts across
 buffers with resharding collectives — the DIMM-Link relayout analogue.
+
+Under expert parallelism a chip holds a share of each layer's experts
+(`MoEConfig.expert_offset`, `n_experts`) and routes over all
+`router_width`: the tables keep one entry per router output, and an
+expert held on another chip has tier `AWAY_T`, which no tier buffer
+serves, so its pairs are never dispatched, counted in a tier, or
+migrated here. On one chip nothing stands in for the others.
 """
 from __future__ import annotations
 
@@ -26,9 +33,10 @@ import numpy as np
 
 from repro.hardware import TPU_V5E, tpu_spec
 from repro.models.layers import Params, dense_init
-from repro.models.moe import expert_ffn, moe_backend, router_topk, shared_ffn
+from repro.models.moe import expert_ffn, moe_backend, route, shared_ffn
 
 HOT_T, WARM_T, COLD_T = 0, 1, 2
+AWAY_T = 3  # held on another chip: no buffer here
 TIER_KEYS = ("hot", "warm", "cold")
 
 
@@ -64,7 +72,7 @@ def validate_tier_sizes(cfg, sizes: TierSizes) -> TierSizes:
     negative cold tier, which used to surface only later as a bogus
     buffer shape deep inside init/dispatch."""
     n_hot, n_warm, n_cold = sizes
-    e = cfg.moe.n_experts
+    e = cfg.moe.n_experts  # the experts held here
     if n_hot < 1 or n_warm < 0 or n_cold < 0:
         raise ValueError(
             f"invalid tier sizes {tuple(sizes)}: need n_hot >= 1 and "
@@ -129,21 +137,22 @@ def _hbm_bytes() -> float:
 def init_tiered_state(rng, cfg, sizes: TierSizes, pad_cold_to: int = 16) -> Params:
     """Tier buffers + routing tables for one MoE layer.
 
-    Initial assignment: experts [0, n_hot) hot, [n_hot, n_hot+n_warm)
-    warm, rest cold — the host engine re-ranks by offline trace analysis
-    before serving and migrates thereafter. The cold buffer is padded to
+    Initial assignment of the held experts, in global-id order: the
+    first n_hot hot, the next n_warm warm, the rest cold — the host
+    engine re-ranks by offline trace analysis before serving and
+    migrates thereafter. The tables have one entry per router output;
+    experts held elsewhere are `AWAY_T`. The cold buffer is padded to
     a multiple of the mesh's data axis so the localized (expert-sharded)
     layout always divides.
     """
     mo = cfg.moe
     d, f = cfg.d_model, mo.d_expert
     dt = jnp.dtype(cfg.param_dtype)
-    e = mo.n_experts
     validate_tier_sizes(cfg, TierSizes(*sizes))
     ks = jax.random.split(rng, 3)
 
-    def buf(key, n):
-        return dense_init(key, (n, 3, d, f), dt)
+    def buf(key, n):  # a tier may be empty (a share of 2 experts: 1/1/0)
+        return dense_init(key, (n, 3, d, f), dt) if n else jnp.zeros((0, 3, d, f), dt)
 
     n_hot, n_warm, n_cold = sizes
     n_cold_slots = -(-n_cold // pad_cold_to) * pad_cold_to
@@ -161,6 +170,10 @@ def init_tiered_state(rng, cfg, sizes: TierSizes, pad_cold_to: int = 16) -> Para
             jnp.arange(n_cold, dtype=jnp.int32),
         ]
     )
+    if mo.is_share:
+        away = mo.router_width - mo.n_experts - mo.expert_offset
+        tier = jnp.pad(tier, (mo.expert_offset, away), constant_values=AWAY_T)
+        slot = jnp.pad(slot, (mo.expert_offset, away))
     return {
         "hot": buf(ks[0], n_hot),
         "warm": buf(ks[1], n_warm),
@@ -214,7 +227,9 @@ def tiered_moe_forward(
     token_mask: jnp.ndarray | None = None,  # [B, S] or [B*S] bool
     backend: str | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (y, expert_counts[E]).
+    """Returns (y, expert_counts[router_width]): pairs of experts held
+    on other chips are counted but not dispatched (their tier is
+    `AWAY_T`); the shared experts run on every token.
 
     cold_capacity_frac (§Perf): cold experts are low-load by scheduling
     invariant (relayout re-stripes anything above tau_cold), so their
@@ -230,14 +245,13 @@ def tiered_moe_forward(
     fused grouped GEMM) or the einsum reference; dispatch/combine and
     the migration machinery are backend-invariant."""
     mo = cfg.moe
-    e, k = mo.n_experts, mo.top_k
+    k = mo.top_k
     b, s, d = x.shape
     kind, _ = moe_backend(cfg, backend)
     t = b * s
     flat = x.reshape(t, d)
 
-    logits = jnp.einsum("td,de->te", flat.astype(jnp.float32), p["router"])
-    _, w, idx = router_topk(logits, k)
+    _, w, idx = route(p, mo, flat)
 
     a_tok = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
     a_exp = idx.reshape(-1).astype(jnp.int32)
@@ -270,7 +284,7 @@ def tiered_moe_forward(
     if mo.n_shared:
         y = y + shared_ffn(p["shared"], x)
     one = 1 if a_live is None else a_live.astype(jnp.int32)
-    counts = jnp.zeros((e,), jnp.int32).at[a_exp].add(one)
+    counts = jnp.zeros((mo.router_width,), jnp.int32).at[a_exp].add(one)
     return y, counts
 
 

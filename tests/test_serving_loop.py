@@ -88,6 +88,29 @@ def test_loop_oversubscribed_queue_drains(setup):
     assert loop.engine.stats.prefills == 10
 
 
+def test_paged_steps_donate_the_kv_pools(setup):
+    """Decode and prefill write K/V into the pool buffers they are
+    handed: every pool array passed in is deleted, so one pool is live
+    however many steps are in flight, and `kv.pools` holds the live one."""
+    cfg, params = setup
+    loop = ServingLoop(cfg, params, batch_size=2, n_groups=1,
+                       cache_len=CACHE_LEN)
+    eng, seen = loop.engine, []
+    for name, at in (("_step_paged", 2), ("_prefill_paged", 5)):
+        def record(*args, _fn=getattr(eng, name), _name=name, _at=at):
+            seen.append((_name, args[_at]))
+            return _fn(*args)
+
+        setattr(eng, name, record)
+    for r in _staggered_requests(cfg, n=3, new_tokens=3):
+        loop.submit(r)
+    assert len(loop.run(max_steps=200)) == 3
+    assert {name for name, _ in seen} == {"_step_paged", "_prefill_paged"}
+    for name, pools in seen:
+        assert all(a.is_deleted() for a in jax.tree.leaves(pools)), name
+    assert not any(a.is_deleted() for a in jax.tree.leaves(loop.kv.pools))
+
+
 def test_mixed_lengths_bucketed_compiles_and_matches_solo(setup):
     """A mixed-length trace (>=6 distinct prompt lengths) stays within
     len(bucket_table) x n_width_buckets(blocks_per_slot) distinct
