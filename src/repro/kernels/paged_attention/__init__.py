@@ -4,6 +4,9 @@ from repro.kernels.paged_attention.ops import (
     resolve_backend,
 )
 from repro.kernels.paged_attention.paged_attention import (
+    group_blocks,
+    kv_walk,
+    lane_view,
     paged_decode_gqa,
     paged_decode_mla,
     paged_prefill_gqa,
@@ -20,6 +23,9 @@ __all__ = [
     "resolve_backend",
     "active_block_width",
     "n_width_buckets",
+    "group_blocks",
+    "kv_walk",
+    "lane_view",
     "paged_decode_gqa",
     "paged_decode_mla",
     "paged_decode_gqa_ref",

@@ -370,6 +370,17 @@ def _paged_write(pool, tables, pos, val):
     return pool.at[bid, pos % bs].set(val)
 
 
+def _paged_write_gqa(write, pool, *args):
+    """`write(pool, *args, val)` through the GQA kernel's lane view of
+    [Kv, hd] (`lane_view`): the same positions and values, in the
+    layout the kernel reads, so the compiler lays the written pool out
+    once for both."""
+    from repro.kernels.paged_attention import lane_view
+
+    *args, val = args
+    return write(lane_view(pool), *args, lane_view(val)).reshape(pool.shape)
+
+
 def paged_scatter(pool, tables, gpos, mask, val):
     """Scatter a CHUNK of new-token seq entries into block pools.
 
@@ -414,8 +425,8 @@ def gqa_decode_paged(p: Params, cfg, x, pool_k, pool_v, tables, pos,
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = apply_rope(q, posv, cfg.rope_theta, cfg.rotary_frac)
     k = apply_rope(k, posv, cfg.rope_theta, cfg.rotary_frac)
-    pool_k = _paged_write(pool_k, tables, pos, k[:, 0])
-    pool_v = _paged_write(pool_v, tables, pos, v[:, 0])
+    pool_k = _paged_write_gqa(_paged_write, pool_k, tables, pos, k[:, 0])
+    pool_v = _paged_write_gqa(_paged_write, pool_v, tables, pos, v[:, 0])
     kind, interpret = _paged_backend(cfg, backend)
     with kernel_span("paged_decode_gqa", KernelBackend(kind, interpret)):
         if kind == "pallas":
@@ -526,8 +537,8 @@ def gqa_prefill_paged(p: Params, cfg, x, pool_k, pool_v, tables, past_len,
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_frac)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_frac)
-    pool_k = paged_scatter(pool_k, tables, positions, mask, k)
-    pool_v = paged_scatter(pool_v, tables, positions, mask, v)
+    pool_k = _paged_write_gqa(paged_scatter, pool_k, tables, positions, mask, k)
+    pool_v = _paged_write_gqa(paged_scatter, pool_v, tables, positions, mask, v)
     lengths = mask.sum(-1).astype(jnp.int32)
     kvh = pool_k.shape[2]
     qk = q.reshape(b, c, kvh, q.shape[2] // kvh, q.shape[3])

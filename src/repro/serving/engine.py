@@ -180,6 +180,12 @@ class EngineStats(RegistryStats):
         "routed_cold": ("pairs", "routed pairs the cold tier served"),
         "routed_elsewhere": (
             "pairs", "routed pairs whose expert is held on another chip"),
+        # --- paged attention, per layer, from each call's positions and
+        # sliced table width (kernels/paged_attention)
+        "attn_kv_blocks": (
+            "blocks", "pool blocks the rows read, through each last query"),
+        "attn_kv_steps": (
+            "steps", "steps the kernel walks them in (P blocks a step)"),
     }
     HISTS = {
         "plan_latency_s": ("s", "host-side plan_migrations latency"),
@@ -573,6 +579,7 @@ class TriMoEServingEngine:
             )
         width = self._active_table_width(pos, live)
         self.decode_table_widths.add(width)
+        self._count_kv_walk(pos, width, 1)
         tables = tables[:, :width]
         logits, self.kv.pools, self.kv.slot_state, counts = self._step_paged(
             self.params, jnp.asarray(tokens), self.kv.pools,
@@ -641,6 +648,7 @@ class TriMoEServingEngine:
                     bids.extend(span.tolist())
                     mask.extend([j < nr] * len(span))
                 self.kv.sanitizer.check_scatter_targets(bids, mask)
+            self._count_kv_walk(past + lens - 1, tw, width)
             logits, self.kv.pools, row_states = self._prefill_paged(
                 self.params, jnp.asarray(toks), jnp.asarray(lens),
                 jnp.asarray(past), jnp.asarray(tables), self.kv.pools,
@@ -721,6 +729,7 @@ class TriMoEServingEngine:
                 bids.extend(span.tolist())
                 mask.extend([bool(live[j])] * len(span))
             self.kv.sanitizer.check_scatter_targets(bids, mask)
+        self._count_kv_walk(past + lens - 1, tw, kw)
         logits, self.kv.pools, row_states, counts = self._verify_paged(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
             jnp.asarray(past), jnp.asarray(tables), self.kv.pools,
@@ -787,6 +796,29 @@ class TriMoEServingEngine:
         candidate move against."""
         hw = self.domains.hw
         return 2.0 * self.shape.weight_bytes / (hw.ici_link_bw * hw.ici_links)
+
+    def _count_kv_walk(self, last, width: int, chunk: int) -> None:
+        """Count one paged-attention call's walk (`attn_kv_*`, per
+        layer): rows whose last real query sits at `last` (-1 for an
+        all-pad row) over a table sliced to `width` columns, `chunk`
+        queries a row. The GQA kernel walks P blocks a step and stops at
+        each row's last block; the MLA kernel steps one block at a time
+        over the whole width."""
+        from repro.kernels.paged_attention import group_blocks, kv_walk
+
+        cfg, bs = self.cfg, self.kv.block_size
+        if cfg.mla is not None:
+            blocks, _ = kv_walk(last, bs, width, 1)
+            steps = len(last) * width
+        else:
+            itemsize = jax.tree.leaves(self.kv.pools)[0].dtype.itemsize
+            p = group_blocks(
+                bs, width, cfg.n_kv_heads, chunk * (cfg.n_heads // cfg.n_kv_heads),
+                cfg.resolved_head_dim, itemsize,
+            )
+            blocks, steps = kv_walk(last, bs, width, p)
+        self.stats.attn_kv_blocks += blocks
+        self.stats.attn_kv_steps += steps
 
     def _dispatched(self, tokens: int) -> None:
         """Record what the MoE step just dispatched ran with."""

@@ -301,6 +301,43 @@ def test_decode_is_chunk_of_one():
     np.testing.assert_array_equal(np.asarray(dec), np.asarray(chk))
 
 
+# ------------------------------------------- multi-block walk at cell shapes
+def _walk_rows(nb, bs, c, p):
+    """(past, lens, dead) of rows that end where the multi-block walk
+    turns: an all-pad row and a decode-style row on the trash table, a
+    one-token row at position 0, rows ending mid-block, on the last
+    token of the first block group, and at the table's full width."""
+    span = nb * bs
+    ends = [0, min(span - 1, bs + 1), min(span, p * bs) - 1, span - 1]
+    past, lens = [0, 5 % span], [0, 1]
+    for last in ends:
+        n = 1 if last == 0 else min(c, last + 1)
+        past.append(last + 1 - n)
+        lens.append(n)
+    return past, lens, [True, True, False, False, False, False]
+
+
+@pytest.mark.parametrize("heads", [(8, 2, 64), (8, 12, 128)],
+                         ids=["granite", "glm"])
+@pytest.mark.parametrize("c", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("nb", [1, 33, 288, 320])
+def test_kernel_block_groups_match_ref(nb, c, heads):
+    """The kernel walks P blocks a step (P * 4 tokens up to 256) and
+    stops at each row's last block: at the cells' table widths, heads
+    and chunks, rows that end anywhere in a group, at its edge, at the
+    full width, and dead or all-pad rows match ref.py and contiguous
+    attention (dead rows only stay finite)."""
+    from repro.kernels.paged_attention import group_blocks
+
+    kv, g, hd = heads
+    bs = 4
+    p = group_blocks(bs, nb, kv, c * g, hd, 4)
+    past, lens, dead = _walk_rows(nb, bs, c, p)
+    _check_chunked_gqa(np.random.default_rng(nb + c), kv=kv, g=g, bs=bs,
+                       nb=nb, c=c, b=len(past), hd=hd, past=past, lens=lens,
+                       dead=dead)
+
+
 # ------------------------------------------------- hypothesis property
 @pytest.mark.slow
 def test_chunked_prefill_kernel_property_random_layouts():
@@ -630,3 +667,62 @@ def test_engine_slices_prefill_tables_to_pow2_active_width(serve_setup):
     assert all(w & (w - 1) == 0 or w == nb for w in widths), widths
     # prompts end at position <= 7 -> at most 2 blocks of 4
     assert max(widths) <= 2 < nb
+
+
+def test_engine_counts_the_kernels_block_walk(serve_setup, monkeypatch):
+    """`engine.attn_kv_blocks` / `attn_kv_steps` equal, summed over a
+    short paged serving run, what each decode and prefill call's rows
+    need: the blocks through each row's last query (block 0 for an
+    all-pad row, no more than the sliced table holds), walked P at a
+    time, computed here from the positions the engine was handed."""
+    import repro.kernels.paged_attention.paged_attention as pam
+    from repro.serving.batching import Request
+    from repro.serving.loop import ServingLoop
+
+    monkeypatch.setattr(pam, "GROUP_TOKENS", 8)  # P = 2 blocks of 4
+    cfg, params = serve_setup
+    loop = ServingLoop(cfg, params, batch_size=2, n_groups=1, cache_len=64)
+    eng, bs, bps = loop.engine, loop.kv.block_size, loop.kv.blocks_per_slot
+    calls = []
+    step, prefill = eng.step_slots_paged, eng.prefill_slots_paged
+
+    def logged_step(tokens, pos, slot_indices, tables, live=None):
+        calls.append(("decode", np.array(pos), np.array(live)))
+        return step(tokens, pos, slot_indices, tables, live=live)
+
+    def logged_prefill(suffixes, slot_indices, lengths, past_len):
+        calls.append(("prefill", np.array(past_len), np.array(lengths)))
+        return prefill(suffixes, slot_indices, lengths, past_len)
+
+    monkeypatch.setattr(eng, "step_slots_paged", logged_step)
+    monkeypatch.setattr(eng, "prefill_slots_paged", logged_prefill)
+    rng = np.random.default_rng(44)
+    for i, n in enumerate((5, 13, 22)):
+        loop.submit(Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n)
+                            .astype(np.int32), max_new_tokens=6))
+    assert len(loop.run(max_steps=200)) == 3
+
+    def width(end_pos):  # the pow2 bucket of table columns, capped
+        w = 1
+        while w * bs <= end_pos:
+            w *= 2
+        return min(w, bps)
+
+    walks = []  # (rows' last query positions, sliced width)
+    for kind, a, b in calls:
+        if kind == "decode":
+            walks.append((a, width(a[b].max())))
+        for c0 in range(0, len(a), eng.prefill_rows) if kind == "prefill" else ():
+            last = a[c0:c0 + eng.prefill_rows] + b[c0:c0 + eng.prefill_rows] - 1
+            pad = [-1] * (eng.prefill_rows - len(last))  # all-pad rows
+            walks.append((list(last) + pad, width(max(last))))
+    blocks = steps = 0
+    for lasts, w in walks:
+        for last in lasts:
+            need = min(max(last, 0) // bs + 1, w)
+            blocks += need
+            steps += -(-need // min(w, 2))
+    assert {k for k, _, _ in calls} == {"decode", "prefill"}
+    assert eng.stats.attn_kv_blocks == blocks
+    assert eng.stats.attn_kv_steps == steps
+    assert blocks > steps  # rows took more than one block a step

@@ -74,12 +74,12 @@ def s(one_chip):
         shape, dtype, sharding=one_chip)
 
 
-def _paged_prefill_gqa(s, chunk, g, hd):
-    n = ROWS * NB + 1
+def _paged_prefill_gqa(s, chunk, g, hd, rows=ROWS, nb=NB):
+    n = rows * nb + 1
     return paged_prefill_gqa.lower(
-        s((ROWS, chunk, KV, g, hd), BF16), s((n, BS, KV, hd), BF16),
-        s((n, BS, KV, hd), BF16), s((ROWS, NB), I32), s((ROWS,), I32),
-        s((ROWS,), I32), interpret=False,
+        s((rows, chunk, KV, g, hd), BF16), s((n, BS, KV, hd), BF16),
+        s((n, BS, KV, hd), BF16), s((rows, nb), I32), s((rows,), I32),
+        s((rows,), I32), interpret=False,
     ).compile().as_text()
 
 
@@ -94,6 +94,19 @@ def test_paged_prefill_gqa_compiles_at_glm_widths(s, chunk):
     """12 query heads per kv head of 128: each block's query tile is
     [chunk x 12, 128] per kv head."""
     assert "tpu_custom_call" in _paged_prefill_gqa(s, chunk, GLM_G, GLM_HD)
+
+
+@pytest.mark.parametrize("rows,chunk,g,hd,nb", [
+    (64, 1, G, HD, 288), (128, 1, GLM_G, GLM_HD, 288), (64, 1, G, HD, 320),
+    (4, 32, G, HD, 256), (4, 32, GLM_G, GLM_HD, 256),
+], ids=["granite_decode", "glm_decode", "chat_decode", "granite_prefill",
+        "glm_prefill"])
+def test_paged_prefill_gqa_compiles_at_cell_shapes(s, rows, chunk, g, hd, nb):
+    """The cells' calls: decode over 64 (granite) and 128 (GLM) rows at
+    decode-long's 288-column cap and chat's 320, and 4-row prefill
+    chunks of 32 tokens at 256 columns. The kernel's K/V buffers hold
+    up to 256 tokens of every kv head, twice, beside the query tile."""
+    assert "tpu_custom_call" in _paged_prefill_gqa(s, chunk, g, hd, rows, nb)
 
 
 def test_paged_prefill_mla_compiles_at_chunk_one(s):
