@@ -14,7 +14,7 @@ package): a config- or call-level *choice* string maps to a concrete
 
 Config knobs (`cfg.paged_attn_backend`, `cfg.moe_backend`) and the
 per-call `backend=` overrides on model entry points
-(`gqa/mla_decode_paged(backend=...)`, `moe_forward(backend=...)`) all
+(`gqa/mla_prefill_paged(backend=...)`, `moe_forward(backend=...)`) all
 feed this single function, so "which code runs where" has exactly one
 answer per choice string.
 

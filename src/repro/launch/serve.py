@@ -42,13 +42,12 @@ def enable_compile_cache() -> str:
 
 
 def build_loop(cfg, *, batch: int, groups: int, cache_len: int,
-               cold_capacity_frac: float = 1.0, seed: int = 0,
-               bucket_table="auto", max_admit_wait: int = 4) -> ServingLoop:
+               seed: int = 0, bucket_table="auto",
+               max_admit_wait: int = 4) -> ServingLoop:
     params = init_params(jax.random.PRNGKey(seed), cfg)
     return ServingLoop(
         cfg, params,
         batch_size=batch, n_groups=groups, cache_len=cache_len,
-        cold_capacity_frac=cold_capacity_frac,
         bucket_table=bucket_table, max_admit_wait=max_admit_wait,
     )
 

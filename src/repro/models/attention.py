@@ -360,176 +360,75 @@ def paged_gather(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return linearize_blocks(pool, tables)
 
 
-def _paged_write(pool, tables, pos, val):
-    """Scatter one new token per row into its block: val [B, ...] lands
-    at pool[tables[b, pos[b] // bs], pos[b] % bs]. Dead rows carry
-    all-trash tables, so their writes fall into the sentinel block."""
-    bs = pool.shape[1]
-    rows = jnp.arange(tables.shape[0])
-    bid = tables[rows, pos // bs]
-    return pool.at[bid, pos % bs].set(val)
-
-
-def _paged_write_gqa(write, pool, *args):
-    """`write(pool, *args, val)` through the GQA kernel's lane view of
-    [Kv, hd] (`lane_view`): the same positions and values, in the
-    layout the kernel reads, so the compiler lays the written pool out
-    once for both."""
-    from repro.kernels.paged_attention import lane_view
-
-    *args, val = args
-    return write(lane_view(pool), *args, lane_view(val)).reshape(pool.shape)
-
-
 def paged_scatter(pool, tables, gpos, mask, val):
-    """Scatter a CHUNK of new-token seq entries into block pools.
+    """Scatter a chunk of new-token seq entries into block pools.
 
     pool [N+1, bs, ...]; tables [W, nb]; gpos [W, C] global positions
-    (past_len + i); mask [W, C] real tokens; val [W, C, ...]. Masked
-    (pad) positions write to the trash block (last pool row), so a
-    right-padded chunk never pollutes a live block — the chunk-width
-    generalization of `_paged_write`'s dead-row contract."""
+    (past_len + i); mask [W, C] real tokens, or None when every token
+    is real (a decode step: its dead rows' tables are all trash);
+    val [W, C, ...]. Masked (pad) positions write to the trash block
+    (last pool row), so a right-padded chunk never pollutes a live
+    block."""
     bs = pool.shape[1]
-    trash = pool.shape[0] - 1
+    if mask is None:
+        w, c = gpos.shape
+        g = gpos.reshape(w * c)
+        bid = tables[jnp.repeat(jnp.arange(w), c), g // bs]
+        return pool.at[bid, g % bs].set(val.reshape(w * c, *val.shape[2:]))
     lb = jnp.minimum(gpos // bs, tables.shape[1] - 1)
     bid = jnp.take_along_axis(tables, lb, axis=1)  # [W, C]
-    bid = jnp.where(mask, bid, trash)
+    bid = jnp.where(mask, bid, pool.shape[0] - 1)
     return pool.at[bid, gpos % bs].set(val)
 
 
-def gqa_decode_paged(p: Params, cfg, x, pool_k, pool_v, tables, pos,
-                     backend=None):
-    """One-token GQA decode against a paged (block-pool) cache.
+def _paged_scatter_gqa(pool, tables, gpos, mask, val):
+    """`paged_scatter` through the GQA kernel's lane view of [Kv, hd]
+    (`lane_view`): the same positions and values, in the layout the
+    kernel reads, so the compiler lays the written pool out once for
+    both."""
+    from repro.kernels.paged_attention import lane_view
 
-    x: [B, 1, D]; pool_k/pool_v: [N+1, bs, Kv, hd] shared block pools
-    (last block is the write trash for dead rows); tables: [B, nb]
-    int32 per-row block tables; pos: int32 [B] absolute positions.
-
-    The new token's K/V is written to its row's tail block, then
-    attention runs over the row's blocks with the same per-row position
-    mask as the contiguous path — same numerics as `gqa_decode` for any
-    block layout (tests/test_paged_kv.py). `backend` (default
-    `cfg.paged_attn_backend`) picks the block-sparse Pallas kernel
-    (kernels/paged_attention — walks only each row's blocks, online
-    softmax) or the dense-gather reference, which linearizes the full
-    table width. Shared (prefix-cache) blocks are full and immutable,
-    so the post-write read can never see another row's in-flight token.
-    """
-    b = x.shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    posv = pos[:, None]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if "bq" in p:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = apply_rope(q, posv, cfg.rope_theta, cfg.rotary_frac)
-    k = apply_rope(k, posv, cfg.rope_theta, cfg.rotary_frac)
-    pool_k = _paged_write_gqa(_paged_write, pool_k, tables, pos, k[:, 0])
-    pool_v = _paged_write_gqa(_paged_write, pool_v, tables, pos, v[:, 0])
-    kind, interpret = _paged_backend(cfg, backend)
-    with kernel_span("paged_decode_gqa", KernelBackend(kind, interpret)):
-        if kind == "pallas":
-            from repro.kernels.paged_attention import paged_decode_gqa
-
-            kvh = pool_k.shape[2]
-            qk = q[:, 0].reshape(b, kvh, q.shape[2] // kvh, q.shape[3])
-            out = paged_decode_gqa(
-                qk, pool_k, pool_v, tables, pos, interpret=interpret
-            ).reshape(b, 1, q.shape[2], q.shape[3])
-        else:
-            keys = paged_gather(pool_k, tables)  # [B, nb*bs, Kv, hd]
-            vals = paged_gather(pool_v, tables)
-            valid = jnp.arange(keys.shape[1])[None, :] <= posv
-            out = _grouped_attention(q, keys, vals, valid=valid)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), pool_k, pool_v
+    out = paged_scatter(lane_view(pool), tables, gpos, mask, lane_view(val))
+    return out.reshape(pool.shape)
 
 
-def mla_decode_paged(p: Params, cfg, x, pool_ckv, pool_krope, tables, pos,
-                     backend=None):
-    """Absorbed MLA decode against paged latent pools.
-
-    pool_ckv: [N+1, bs, r]; pool_krope: [N+1, bs, rope_dim]; tables:
-    [B, nb]; pos: [B]. Same math (and fp32 accumulation) as
-    `mla_decode` over the row's blocks; `backend` as in
-    `gqa_decode_paged` — the Pallas kernel attends in latent space and
-    the wv_b expansion stays out here."""
-    m = cfg.mla
-    b = x.shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    posv = pos[:, None]
-
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, posv, cfg.rope_theta)
-
-    kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
-    ckv_new, krope_new = jnp.split(kv_a, [m.kv_lora_rank], axis=-1)
-    krope_new = apply_rope(krope_new[:, :, None, :], posv, cfg.rope_theta)[:, :, 0, :]
-    pool_ckv = _paged_write(pool_ckv, tables, pos, ckv_new[:, 0])
-    pool_krope = _paged_write(pool_krope, tables, pos, krope_new[:, 0])
-
-    wk_b, wv_b = jnp.split(p["wkv_b"], [m.qk_nope_head_dim], axis=-1)
-    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wk_b,
-                       preferred_element_type=jnp.float32)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
-    kind, interpret = _paged_backend(cfg, backend)
-    with kernel_span("paged_decode_mla", KernelBackend(kind, interpret)):
-        if kind == "pallas":
-            from repro.kernels.paged_attention import paged_decode_mla
-
-            o_lat = paged_decode_mla(
-                q_lat[:, 0], q_rope[:, 0].astype(jnp.float32), pool_ckv,
-                pool_krope, tables, pos, scale=scale, interpret=interpret,
-            )[:, None]  # [B,1,H,r] fp32
-        else:
-            cache_ckv = paged_gather(pool_ckv, tables)  # [B, nb*bs, r]
-            cache_krope = paged_gather(pool_krope, tables)
-            s = (
-                jnp.einsum("bshr,btr->bhst", q_lat, cache_ckv,
-                           preferred_element_type=jnp.float32)
-                + jnp.einsum("bshk,btk->bhst", q_rope, cache_krope,
-                             preferred_element_type=jnp.float32)
-            ) * scale
-            valid = jnp.arange(cache_ckv.shape[1])[None, :] <= posv
-            s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-            pattn = jax.nn.softmax(s, axis=-1)
-            o_lat = jnp.einsum("bhst,btr->bshr", pattn, cache_ckv,
-                               preferred_element_type=jnp.float32)
-    o = jnp.einsum("bshr,rhk->bshk", o_lat, wv_b,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), pool_ckv, pool_krope
+def _chunk_lengths(token_mask, b, c):
+    """Real tokens per row: the kernel's per-row query count."""
+    if token_mask is None:
+        return jnp.full((b,), c, jnp.int32)
+    return jnp.asarray(token_mask, bool).sum(-1).astype(jnp.int32)
 
 
-# -------------------------------------------- paged chunked suffix prefill
+# ------------------------------------------- paged chunked attention
 def gqa_prefill_paged(p: Params, cfg, x, pool_k, pool_v, tables, past_len,
                       positions, token_mask, backend=None):
-    """Chunked suffix prefill against the paged cache — the same
-    write-then-attend contract as `gqa_decode_paged`, widened to a
-    `[rows, chunk]` query tile (decode is this path at chunk 1).
+    """GQA attention of a chunk of new tokens against the paged cache:
+    prefill chunks, speculative verify, and decode (the chunk of 1,
+    `past_len = pos`).
 
-    x: [W, C, D] — each row's uncached-suffix chunk, right-padded;
-    pool_k/pool_v: [N+1, bs, Kv, hd]; tables: [W, nb] block tables
-    SLICED by the caller to the pow2 active width covering every row's
-    prefix + suffix end; past_len: [W] tokens already cached before the
-    chunk; positions: [W, C] absolute positions (past_len + arange);
-    token_mask: [W, C] real tokens (None = all real).
+    x: [W, C, D] — each row's chunk, right-padded; pool_k/pool_v:
+    [N+1, bs, Kv, hd] shared block pools (the last block is the write
+    trash); tables: [W, nb] block tables SLICED by the caller to the
+    pow2 active width covering every row's last token; past_len: [W]
+    tokens already cached before the chunk; positions: [W, C] absolute
+    positions (past_len + arange); token_mask: [W, C] real tokens, or
+    None when all are (dead rows then need all-trash tables).
 
     The chunk's K/V is scattered into its rows' blocks first (pads to
     the trash block), then attention walks each row's blocks with
     per-query causal masking — the cached prefix AND the chunk's own
     earlier tokens are both just pool reads, which is what makes the
-    path identical for cold admission, prefix-hit suffixes, and
-    mid-prompt piggyback chunks. `backend` as in `gqa_decode_paged`.
+    path identical for cold admission, prefix-hit suffixes, mid-prompt
+    piggyback chunks and decode. Shared (prefix-cache) blocks are full
+    and immutable, so the post-write read never sees another row's
+    in-flight token. `backend` (default `cfg.paged_attn_backend`) picks
+    the block-sparse Pallas kernel (kernels/paged_attention — walks only
+    each row's blocks, online softmax) or the dense-gather reference.
 
     Returns (out [W, C, D], pool_k, pool_v).
     """
     b, c, _ = x.shape
     past_len = jnp.asarray(past_len, jnp.int32)
-    mask = (
-        jnp.ones((b, c), bool) if token_mask is None
-        else jnp.asarray(token_mask, bool)
-    )
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -537,9 +436,9 @@ def gqa_prefill_paged(p: Params, cfg, x, pool_k, pool_v, tables, past_len,
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_frac)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_frac)
-    pool_k = _paged_write_gqa(paged_scatter, pool_k, tables, positions, mask, k)
-    pool_v = _paged_write_gqa(paged_scatter, pool_v, tables, positions, mask, v)
-    lengths = mask.sum(-1).astype(jnp.int32)
+    pool_k = _paged_scatter_gqa(pool_k, tables, positions, token_mask, k)
+    pool_v = _paged_scatter_gqa(pool_v, tables, positions, token_mask, v)
+    lengths = _chunk_lengths(token_mask, b, c)
     kvh = pool_k.shape[2]
     qk = q.reshape(b, c, kvh, q.shape[2] // kvh, q.shape[3])
     kind, interpret = _paged_backend(cfg, backend)
@@ -561,18 +460,14 @@ def gqa_prefill_paged(p: Params, cfg, x, pool_k, pool_v, tables, past_len,
 
 def mla_prefill_paged(p: Params, cfg, x, pool_ckv, pool_krope, tables,
                       past_len, positions, token_mask, backend=None):
-    """Absorbed chunked MLA suffix prefill against paged latent pools —
-    `mla_decode_paged` widened to a `[rows, chunk]` query tile, same
-    fp32 accumulation and latent-space value read (wv_b expansion out
-    here). Arguments as in `gqa_prefill_paged` with pools
-    [N+1, bs, r | rope_dim]. Returns (out [W, C, D], pools)."""
+    """Absorbed MLA attention of a chunk against paged latent pools —
+    `mla_decode`'s math (fp32 accumulation, latent-space value read,
+    wv_b expansion out here) over a `[rows, chunk]` query tile.
+    Arguments as in `gqa_prefill_paged` with pools [N+1, bs, r |
+    rope_dim]. Returns (out [W, C, D], pools)."""
     m = cfg.mla
     b, c, _ = x.shape
     past_len = jnp.asarray(past_len, jnp.int32)
-    mask = (
-        jnp.ones((b, c), bool) if token_mask is None
-        else jnp.asarray(token_mask, bool)
-    )
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
@@ -582,9 +477,10 @@ def mla_prefill_paged(p: Params, cfg, x, pool_ckv, pool_krope, tables,
     krope_new = apply_rope(
         krope_new[:, :, None, :], positions, cfg.rope_theta
     )[:, :, 0, :]
-    pool_ckv = paged_scatter(pool_ckv, tables, positions, mask, ckv_new)
-    pool_krope = paged_scatter(pool_krope, tables, positions, mask, krope_new)
-    lengths = mask.sum(-1).astype(jnp.int32)
+    pool_ckv = paged_scatter(pool_ckv, tables, positions, token_mask, ckv_new)
+    pool_krope = paged_scatter(pool_krope, tables, positions, token_mask,
+                               krope_new)
+    lengths = _chunk_lengths(token_mask, b, c)
 
     wk_b, wv_b = jnp.split(p["wkv_b"], [m.qk_nope_head_dim], axis=-1)
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wk_b,

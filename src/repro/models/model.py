@@ -228,14 +228,14 @@ def apply_layer(
     forward of each row's real prefix.
 
     Paged KV (serving/paged_kv.py): `paged_tables` switches attention
-    to the block-pool cache — `cache` then carries POOL leaves
-    ([N+1, bs, ...]) for k/v/ckv/krope and per-row leaves for recurrent
-    state. Decode and full mode share ONE block-sparse paged-attention
-    path (kernels/paged_attention — decode is the chunk-of-1 case): in
-    full mode the chunk's K/V is scattered into the rows' blocks and
-    attention walks each row's table with per-query causal masking
-    against `paged_past_len` cached prefix tokens; returned seq leaves
-    are the UPDATED POOLS.
+    to the block-pool cache in either mode — `cache` then carries POOL
+    leaves ([N+1, bs, ...]) for k/v/ckv/krope and per-row leaves for
+    recurrent state. Attention scatters the chunk's K/V into the rows'
+    blocks and walks each row's table with per-query causal masking
+    against `paged_past_len` cached tokens (kernels/paged_attention);
+    returned seq leaves are the UPDATED POOLS. In decode mode the chunk
+    is one token and unmasked: dead rows write and read through
+    all-trash tables.
     """
     mixer, ffn = sig
     e = cfg.moe.router_width if cfg.moe is not None else 1
@@ -243,13 +243,12 @@ def apply_layer(
     counts = jnp.zeros((e,), jnp.int32)
     new_cache: Params = {}
 
-    fmask = token_mask if mode == "full" else None  # [B, S] prefill mask
+    fmask = token_mask if mode == "full" else None  # [B, S] chunk mask
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if mixer in ("attn", "mla"):
-        if mode == "full" and paged_tables is not None:
-            # chunked suffix prefill: write the chunk's K/V into the
-            # rows' blocks, then block-sparse paged attention (shared
-            # with decode = chunk of 1)
+        if paged_tables is not None:
+            # write the chunk's K/V into the rows' blocks, then
+            # block-sparse paged attention (decode = chunk of 1)
             if mixer == "attn":
                 y, pk, pv = attn.gqa_prefill_paged(
                     p["mixer"], cfg, h, cache["k"], cache["v"],
@@ -276,19 +275,6 @@ def apply_layer(
                 )
                 if cache is not None:
                     new_cache.update(ckv=ckv, krope=krope)
-        elif paged_tables is not None:
-            if mixer == "attn":
-                y, pk, pv = attn.gqa_decode_paged(
-                    p["mixer"], cfg, h, cache["k"], cache["v"],
-                    paged_tables, pos,
-                )
-                new_cache.update(k=pk, v=pv)
-            else:
-                y, pc, pk = attn.mla_decode_paged(
-                    p["mixer"], cfg, h, cache["ckv"], cache["krope"],
-                    paged_tables, pos,
-                )
-                new_cache.update(ckv=pc, krope=pk)
         else:
             if mixer == "attn":
                 y, ck, cv = attn.gqa_decode(p["mixer"], cfg, h, cache["k"], cache["v"], pos)
@@ -664,275 +650,102 @@ def _scan_pool_groups(body, x, pools, xs, n_groups: int):
     return x, pools, ys
 
 
-def decode_step_paged(
+def forward_paged(
     params: Params,
     cfg: ModelConfig,
     tokens: jnp.ndarray,
     pools: Params,
-    states: Params,
-    tables: jnp.ndarray,
-    pos,
-    tiered: Params | None = None,
-    cold_capacity_frac: float = 0.25,
-    token_mask: jnp.ndarray | None = None,
-):
-    """One decode step against the paged KV cache.
-
-    tokens [B,1]; `pools` holds the shared block pools (seq leaves,
-    [N+1, bs, ...]; stack leaves carry the scan-group dim first);
-    `states` the active rows' non-seq leaves ([B, ...]); tables [B, nb]
-    per-row block tables; pos [B] absolute positions. Returns
-    (logits, new_pools, new_states, expert_counts) — mirror of
-    `decode_step` with attention layers reading/writing pools by block
-    table (attn.gqa_decode_paged / attn.mla_decode_paged)."""
-    unrolled_idx, n_groups, period = stack_plan(cfg)
-    x = embed(params["embed"], tokens)
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (tokens.shape[0],))
-    positions = pos[:, None]
-    tables = jnp.asarray(tables, jnp.int32)
-    tmask = None if token_mask is None else token_mask.reshape(-1, 1)
-
-    new_pools: Params = {}
-    new_states: Params = {}
-    counts_all = []
-    for li in unrolled_idx:
-        sig = layer_signature(cfg, li)
-        ts = tiered.get(f"layer{li}") if tiered else None
-        cache_l = {**pools[f"layer{li}"], **states[f"layer{li}"]}
-        x, _, counts, nc = apply_layer(
-            cfg, sig, params[f"layer{li}"], x, positions,
-            mode="decode", cache=cache_l, pos=pos, tiered_state=ts,
-            cold_capacity_frac=cold_capacity_frac, token_mask=tmask,
-            paged_tables=tables,
-        )
-        new_pools[f"layer{li}"] = {
-            k: v for k, v in nc.items() if k in SEQ_CACHE_KEYS
-        }
-        new_states[f"layer{li}"] = {
-            **states[f"layer{li}"],
-            **{k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS},
-        }
-        counts_all.append(counts)
-
-    tiered_stack = tiered.get("stack") if tiered else None
-
-    def body(x, pool_c, inp):
-        p, state_c, ts_stack = inp
-        np_, ns_ = {}, {}
-        cnts = []
-        for j, sig in enumerate(period):
-            ts = ts_stack.get(f"slot{j}") if ts_stack else None
-            cache_l = {**pool_c[f"slot{j}"], **state_c[f"slot{j}"]}
-            x, _, counts, nc = apply_layer(
-                cfg, sig, p[f"slot{j}"], x, positions,
-                mode="decode", cache=cache_l, pos=pos, tiered_state=ts,
-                cold_capacity_frac=cold_capacity_frac, token_mask=tmask,
-                paged_tables=tables,
-            )
-            np_[f"slot{j}"] = {
-                k: v for k, v in nc.items() if k in SEQ_CACHE_KEYS
-            }
-            ns_[f"slot{j}"] = {
-                **state_c[f"slot{j}"],
-                **{k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS},
-            }
-            cnts.append(counts)
-        return x, np_, (ns_, jnp.stack(cnts))
-
-    x, stack_pools, (stack_states, counts) = _scan_pool_groups(
-        body, x, pools["stack"],
-        (params["stack"], states["stack"], tiered_stack or {}), n_groups,
-    )
-    new_pools["stack"] = stack_pools
-    new_states["stack"] = stack_states
-    logits = _logits(params, cfg, x)[:, 0]
-    e = cfg.moe.router_width if cfg.moe is not None else 1
-    counts = counts.reshape(-1, e)
-    if counts_all:
-        counts = jnp.concatenate([jnp.stack(counts_all), counts], axis=0)
-    return logits, new_pools, new_states, counts
-
-
-def prefill_paged(
-    params: Params,
-    cfg: ModelConfig,
-    batch: Dict[str, Any],
-    pools: Params,
+    states: Params | None,
     tables: jnp.ndarray,
     past_len: jnp.ndarray,
     token_mask: jnp.ndarray,
-    tiered: Params | None = None,
-    cold_capacity_frac: float = 0.25,
+    tiered: Params | None,
+    *,
+    all_logits: bool = False,
 ):
-    """Suffix-only masked prefill against the paged cache — one chunk
-    of the CHUNKED paged-attention path (decode is the chunk-of-1 case
-    of the same kernels).
+    """One forward pass against the paged KV cache: a decode step, a
+    prefill chunk or a speculative verify chunk.
 
-    batch["tokens"] [W, S] carries each row's UNCACHED suffix chunk,
-    right-padded to a bucket width and masked by `token_mask`;
-    `past_len` [W] is the token count already present in the cache
-    before this chunk (0 for cold admissions; a prefix-cache hit or the
-    previous piggyback chunk otherwise); tables [W, nbw] are the rows'
-    block tables, SLICED by the caller to the pow2 active width
-    covering prefix + suffix (engine.prefill_slots_paged) — one compile
-    per (suffix bucket, table-width bucket). Attention layers scatter
-    the chunk's K/V into its blocks and walk the tables block-sparsely
-    with per-query causal masking (attn.gqa/mla_prefill_paged) — the
-    cached prefix is never dense-gathered at full table width. Rows
-    with past_len > 0 require an attention-only arch (recurrent state
-    cannot be reconstructed from a token-keyed prefix —
-    serving/paged_kv.py and the loop's chunked_prefill gate this);
-    recurrent layers run the ordinary masked forward and return per-row
-    state.
+    tokens [W, C] carry each row's chunk at positions past_len + [0, C),
+    right-padded and masked by `token_mask` [W, C]; `pools` holds the
+    shared block pools (seq leaves, [N+1, bs, ...]; stack leaves carry
+    the scan-group dim first); tables [W, nb] are the rows' block
+    tables, sliced by the caller to the pow2 width covering every row's
+    last token (engine._run_paged). Attention layers scatter the
+    chunk's K/V into its blocks and walk the tables block-sparsely with
+    per-query causal masking (attn.gqa/mla_prefill_paged).
 
-    Returns (last_real_token_logits [W, V], new_pools, new_states).
+    Recurrent mixers follow `states`, the rows' non-seq leaves
+    ([W, ...]): given, each is stepped one token (decode; C must be 1);
+    None, the chunk runs from a zero state, so rows with past_len > 0
+    need an attention-only arch (serving/paged_kv.prefix_cacheable).
+    A decode step's attention is unmasked: its dead rows' tables must
+    be all trash. MoE dispatch is dropless and masked by `token_mask`.
+
+    Returns (logits, new_pools, new_states, expert_counts): logits are
+    [W, C, V] with `all_logits` (verify compares every position) and
+    otherwise [W, V] at each row's last real token (a chunk never
+    projects every position through the vocabulary).
     """
-    tokens = batch["tokens"]
     b, s = tokens.shape
+    assert cfg.encdec is None, "the paged forward does not support enc-dec"
+    assert states is None or s == 1, "recurrent state steps one token"
+    mode = "full" if states is None else "decode"
+    states = states or {}
     unrolled_idx, n_groups, period = stack_plan(cfg)
-    assert cfg.encdec is None, "paged prefill does not support enc-dec"
     x = embed(params["embed"], tokens)
     past_len = jnp.asarray(past_len, jnp.int32)
     tables = jnp.asarray(tables, jnp.int32)
     positions = past_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
 
-    def run_layer(p, sig, x, cache_pools, ts):
-        mixer, _ = sig
-        is_attn = mixer in ("attn", "mla")
-        x, _, _, nc = apply_layer(
-            cfg, sig, p, x, positions, mode="full",
-            cache=cache_pools if is_attn else {},
-            tiered_state=ts, cold_capacity_frac=cold_capacity_frac,
-            token_mask=token_mask,
-            paged_tables=tables if is_attn else None,
-            paged_past_len=past_len if is_attn else None,
-        )
-        new_pool = {k: v for k, v in nc.items() if k in SEQ_CACHE_KEYS}
-        new_state = {k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS}
-        return x, new_pool, new_state
-
-    new_pools: Params = {}
-    new_states: Params = {}
-    for li in unrolled_idx:
-        sig = layer_signature(cfg, li)
-        ts = tiered.get(f"layer{li}") if tiered else None
-        x, npool, nstate = run_layer(
-            params[f"layer{li}"], sig, x, pools[f"layer{li}"], ts
-        )
-        new_pools[f"layer{li}"] = npool
-        new_states[f"layer{li}"] = nstate
-
-    tiered_stack = tiered.get("stack") if tiered else None
-
-    def body(x, pool_c, inp):
-        p, ts_stack = inp
-        np_, ns_ = {}, {}
-        for j, sig in enumerate(period):
-            ts = ts_stack.get(f"slot{j}") if ts_stack else None
-            x, npool, nstate = run_layer(
-                p[f"slot{j}"], sig, x, pool_c[f"slot{j}"], ts
-            )
-            np_[f"slot{j}"] = npool
-            ns_[f"slot{j}"] = nstate
-        return x, np_, ns_
-
-    x, stack_pools, stack_states = _scan_pool_groups(
-        body, x, pools["stack"], (params["stack"], tiered_stack or {}),
-        n_groups,
-    )
-    new_pools["stack"] = stack_pools
-    new_states["stack"] = stack_states
-    last = jnp.maximum(token_mask.sum(-1).astype(jnp.int32) - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = _logits(params, cfg, x_last)[:, 0]
-    return logits, new_pools, new_states
-
-
-def decode_verify(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,
-    pools: Params,
-    tables: jnp.ndarray,
-    past_len: jnp.ndarray,
-    token_mask: jnp.ndarray,
-    tiered: Params | None = None,
-    cold_capacity_frac: float = 0.25,
-):
-    """Speculative chunk-of-k verification against the paged cache.
-
-    Identical forward to `prefill_paged` — tokens [W, K] carry each
-    row's [sampled token, draft_1..draft_{k-1}] chunk at vector
-    positions past_len + [0..K), right-padded and masked per row by
-    `token_mask` (per-row draft counts differ) — but it keeps what
-    prefill throws away: the logits at EVERY chunk position (the accept
-    rule compares draft i against argmax of position i-1's logits) and
-    the per-layer expert counts (a verify step feeds the tier scheduler
-    exactly like the decode step it replaces). In fp32 the chunk-of-k
-    logits are bit-exact vs k sequential decode steps: decode is the
-    chunk-of-1 case of the same kernel family.
-
-    Returns (logits [W, K, V], new_pools, new_states, expert_counts).
-    """
-    b, s = tokens.shape
-    unrolled_idx, n_groups, period = stack_plan(cfg)
-    assert cfg.encdec is None, "paged verify does not support enc-dec"
-    x = embed(params["embed"], tokens)
-    past_len = jnp.asarray(past_len, jnp.int32)
-    tables = jnp.asarray(tables, jnp.int32)
-    positions = past_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-
-    def run_layer(p, sig, x, cache_pools, ts):
-        mixer, _ = sig
-        is_attn = mixer in ("attn", "mla")
+    def run_layer(p, sig, x, pool, state, ts):
         x, _, counts, nc = apply_layer(
-            cfg, sig, p, x, positions, mode="full",
-            cache=cache_pools if is_attn else {},
-            tiered_state=ts, cold_capacity_frac=cold_capacity_frac,
-            token_mask=token_mask,
-            paged_tables=tables if is_attn else None,
-            paged_past_len=past_len if is_attn else None,
+            cfg, sig, p, x, positions, mode=mode, cache={**pool, **state},
+            tiered_state=ts, cold_capacity_frac=1.0, token_mask=token_mask,
+            paged_tables=tables, paged_past_len=past_len,
         )
         new_pool = {k: v for k, v in nc.items() if k in SEQ_CACHE_KEYS}
-        new_state = {k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS}
+        new_state = {
+            **state, **{k: v for k, v in nc.items() if k not in SEQ_CACHE_KEYS}
+        }
         return x, new_pool, new_state, counts
 
     new_pools: Params = {}
     new_states: Params = {}
     counts_all = []
     for li in unrolled_idx:
-        sig = layer_signature(cfg, li)
-        ts = tiered.get(f"layer{li}") if tiered else None
-        x, npool, nstate, counts = run_layer(
-            params[f"layer{li}"], sig, x, pools[f"layer{li}"], ts
+        name = f"layer{li}"
+        x, new_pools[name], new_states[name], counts = run_layer(
+            params[name], layer_signature(cfg, li), x, pools[name],
+            states.get(name, {}), tiered.get(name) if tiered else None,
         )
-        new_pools[f"layer{li}"] = npool
-        new_states[f"layer{li}"] = nstate
         counts_all.append(counts)
 
-    tiered_stack = tiered.get("stack") if tiered else None
-
     def body(x, pool_c, inp):
-        p, ts_stack = inp
-        np_, ns_ = {}, {}
-        cnts = []
+        p, state_c, ts_stack = inp
+        np_, ns_, cnts = {}, {}, []
         for j, sig in enumerate(period):
-            ts = ts_stack.get(f"slot{j}") if ts_stack else None
-            x, npool, nstate, counts = run_layer(
-                p[f"slot{j}"], sig, x, pool_c[f"slot{j}"], ts
+            name = f"slot{j}"
+            x, np_[name], ns_[name], counts = run_layer(
+                p[name], sig, x, pool_c[name], state_c.get(name, {}),
+                ts_stack.get(name) if ts_stack else None,
             )
-            np_[f"slot{j}"] = npool
-            ns_[f"slot{j}"] = nstate
             cnts.append(counts)
         return x, np_, (ns_, jnp.stack(cnts))
 
-    x, stack_pools, (stack_states, counts) = _scan_pool_groups(
-        body, x, pools["stack"], (params["stack"], tiered_stack or {}),
+    x, new_pools["stack"], (new_states["stack"], counts) = _scan_pool_groups(
+        body, x, pools["stack"],
+        (params["stack"], states.get("stack", {}),
+         (tiered.get("stack") if tiered else None) or {}),
         n_groups,
     )
-    new_pools["stack"] = stack_pools
-    new_states["stack"] = stack_states
-    logits = _logits(params, cfg, x)
+    if all_logits:
+        logits = _logits(params, cfg, x)
+    else:
+        if s > 1:  # each row's last real token
+            last = jnp.maximum(token_mask.sum(-1).astype(jnp.int32) - 1, 0)
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        logits = _logits(params, cfg, x)[:, 0]
     e = cfg.moe.router_width if cfg.moe is not None else 1
     counts = counts.reshape(-1, e)
     if counts_all:

@@ -13,7 +13,7 @@ wide gate+up GEMM) and decode-shaped buffers (S == 1, small capacity)
 run the batched expert GEMV (`kernels/expert_gemv.cold_expert_ffn`,
 weights streamed past the resident tokens exactly once); "ref" keeps
 the inline grouped einsums. `moe_forward(backend=...)` overrides the
-config per call, mirroring `gqa/mla_decode_paged(backend=...)`.
+config per call, mirroring `gqa/mla_prefill_paged(backend=...)`.
 
 Returns per-expert token counts alongside the output — the load signal
 the TriMoE predictor/scheduler (core/) consumes.

@@ -6,7 +6,6 @@ from repro.serving.engine import (
     strip_expert_weights,
 )
 from repro.serving.kv_cache import (
-    SlotKVCache,
     cache_bytes,
     cache_spec,
     gather_slots,
@@ -32,7 +31,7 @@ from repro.serving.tiered_moe import (
 __all__ = [
     "BucketTable", "Request", "ZigzagBatcher", "TriMoEServingEngine",
     "fill_tiers_from_params", "init_tiered_for_model", "strip_expert_weights",
-    "SlotKVCache", "cache_bytes", "cache_spec", "gather_slots", "reset_slots",
+    "cache_bytes", "cache_spec", "gather_slots", "reset_slots",
     "scatter_slots", "LoopStats", "ServingLoop", "TierSizes",
     "apply_migrations", "init_tiered_state", "tier_sizes", "tiered_moe_forward",
     "PagedKVCache", "RadixPrefixIndex", "init_paged_cache", "prefix_cacheable",

@@ -1,7 +1,7 @@
 """TriMoE serving engine: the online loop of paper §4 on the TPU runtime.
 
 Per decode step:
-  1. jitted `decode_step(..., tiered=...)` executes attention + the
+  1. jitted `forward_paged(..., tiered=...)` executes attention + the
      three-tier MoE and returns per-expert token counts;
   2. the host updates the EMA predictor (Eq. 8) with the realized loads
      (`observe`);
@@ -38,18 +38,10 @@ from repro.core.policy import SchedulerPolicy, resolve_policy
 from repro.core.predictor import EMALoadPredictor
 from repro.core.tiers import COLD, HOT, WARM, TierThresholds
 from repro.models.layers import Params
-from repro.models.model import (
-    decode_step,
-    decode_step_paged,
-    decode_verify,
-    layer_signature,
-    prefill,
-    prefill_paged,
-    stack_plan,
-)
+from repro.models.model import forward_paged, layer_signature, stack_plan
 from repro.obs import resolve_obs
 from repro.obs.metrics import RegistryStats
-from repro.serving.kv_cache import SlotKVCache, gather_slots, scatter_slots
+from repro.serving.kv_cache import gather_slots, scatter_slots
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.tiered_moe import (
     AWAY_T,
@@ -205,33 +197,33 @@ class MoEDispatch(NamedTuple):
 class TriMoEServingEngine:
     """Host-side online loop at smoke/example scale (single device).
 
-    `cache` may be a raw cache pytree (legacy full-batch stepping) or a
-    SlotKVCache (continuous batching: the ServingLoop admits requests
-    into slots, and decode gathers/scatters only the active zigzag
-    group's rows). `cold_capacity_frac=1.0` keeps the tiered runtime
-    exactly dropless so batched serving is token-for-token identical to
-    single-request generation; lower it to trade exactness for dispatch
-    buffer size (paper §Perf).
+    `kv` is the PagedKVCache the ServingLoop admits requests into. Decode
+    steps, prefill chunks and speculative verify chunks are one paged
+    forward pass (`models.model.forward_paged`), prepared on the host by
+    one path (`_run_paged`): decode steps a zigzag group's rows by one
+    token, prefill writes admitted rows' uncached suffixes, verify
+    scores a group's draft chunks. The MoE layers run through the
+    tiered runtime dropless, so batched serving is token-for-token
+    identical to single-request generation.
 
     The engine owns `tiered`: migrations donate the tier arrays and
     swap experts in place, so once `apply_planned` has run, the arrays
     the caller handed in are deleted and `self.tiered` holds their
     successors. A caller that reads its own tier arrays afterwards
-    passes the engine a copy. Likewise the paged steps donate the KV
-    pools: after a paged decode, prefill or verify, `kv.pools` holds the
-    only live pool arrays.
+    passes the engine a copy. Likewise the paged calls donate the KV
+    pools: after a decode, prefill or verify, `kv.pools` holds the only
+    live pool arrays.
     """
 
     def __init__(
         self,
         cfg: ModelConfig,
         params: Params,
-        cache,
+        kv: PagedKVCache,
         tiered: Params,
         sizes: Optional[TierSizes] = None,
         plan_size: Optional[int] = None,  # DEPRECATED -> policy.plan_size
         thresholds: Optional[TierThresholds] = None,  # DEPRECATED -> policy
-        cold_capacity_frac: float = 1.0,
         prefill_rows: int = 4,  # bucketed prefill batch width (row pad)
         scheduler: Optional[SchedulerPolicy] = None,
         obs=None,  # Observability | ObsConfig | None (repro.obs)
@@ -245,10 +237,7 @@ class TriMoEServingEngine:
         self.obs = resolve_obs(cfg, obs, caller="TriMoEServingEngine")
         self._tr = self.obs.tracer
         self.params = strip_expert_weights(params, cfg)
-        self.kv = (
-            cache if isinstance(cache, (SlotKVCache, PagedKVCache))
-            else SlotKVCache.from_cache(cache)
-        )
+        self.kv = kv
         self.tiered = tiered
         self.sizes = sizes or tier_sizes(cfg)
         self.policy = resolve_policy(
@@ -256,7 +245,6 @@ class TriMoEServingEngine:
             caller="TriMoEServingEngine",
         )
         self.th = self.policy.thresholds
-        self.cold_capacity_frac = cold_capacity_frac
         n_moe = sum(cfg.uses_moe_layer(i) for i in range(cfg.n_layers))
         self.predictor = EMALoadPredictor(
             n_moe, cfg.moe.n_experts, alpha=self.policy.ema_alpha,
@@ -281,72 +269,38 @@ class TriMoEServingEngine:
         self.paged_attn_backend = resolve_backend(
             getattr(cfg, "paged_attn_backend", "auto")
         )
-        self._step = jax.jit(
-            lambda p, t, c, pos, ts: decode_step(
-                p, cfg, t, c, pos, tiered=ts,
-                cold_capacity_frac=cold_capacity_frac,
-            )
-        )
 
-        def step_slots(p, t, c, idx, pos, ts, live):
-            sub = gather_slots(c, idx)
-            logits, sub, counts = decode_step(
-                p, cfg, t, sub, pos, tiered=ts,
-                cold_capacity_frac=cold_capacity_frac, token_mask=live,
-            )
-            return logits, scatter_slots(c, sub, idx), counts
-
-        self._step_slots = jax.jit(step_slots)
-        self._prefill = jax.jit(
-            lambda p, toks, ts, cache_len: prefill(
-                p, cfg, {"tokens": toks}, cache_len=cache_len, tiered=ts,
-                cold_capacity_frac=cold_capacity_frac,
-            ),
-            static_argnums=(3,),
-        )
-
-        def prefill_masked(p, toks, lens, ts, cache_len):
-            mask = jnp.arange(toks.shape[1])[None, :] < lens[:, None]
-            return prefill(
-                p, cfg, {"tokens": toks}, cache_len=cache_len, tiered=ts,
-                cold_capacity_frac=cold_capacity_frac, token_mask=mask,
-            )
-
-        self._prefill_masked = jax.jit(prefill_masked, static_argnums=(4,))
-
-        # --- paged-KV variants: decode/prefill against the block pools.
-        # The pools are donated: each call writes its tokens' K/V into
-        # the caller's pool buffers in place, so one pool is live however
-        # many steps are in flight (kv.pools holds the successor).
+        # --- the paged forward, jitted per phase (the XLA modules keep
+        # the names `jit_step_paged` and `jit_prefill_paged_fn`, which
+        # the benchmark's trace reads). The pools are donated: each call
+        # writes its tokens' K/V into the caller's pool buffers in place,
+        # so one pool is live however many steps are in flight (kv.pools
+        # holds the successor). Decode steps the rows' recurrent state;
+        # prefill and verify chunks start it from zero.
         def step_paged(p, t, pools, states, tables, idx, pos, ts, live):
-            sub = gather_slots(states, idx)
-            logits, new_pools, new_sub, counts = decode_step_paged(
-                p, cfg, t, pools, sub, tables, pos, tiered=ts,
-                cold_capacity_frac=cold_capacity_frac, token_mask=live,
+            return forward_paged(
+                p, cfg, t, pools, gather_slots(states, idx), tables, pos,
+                live[:, None], ts,
             )
-            return logits, new_pools, scatter_slots(states, new_sub, idx), counts
 
-        self._step_paged = jax.jit(step_paged, donate_argnums=2)
+        def chunk_mask(toks, lens):
+            return jnp.arange(toks.shape[1])[None, :] < lens[:, None]
 
         def prefill_paged_fn(p, toks, lens, past, tables, pools, ts):
-            mask = jnp.arange(toks.shape[1])[None, :] < lens[:, None]
-            return prefill_paged(
-                p, cfg, {"tokens": toks}, pools, tables, past, mask,
-                tiered=ts, cold_capacity_frac=cold_capacity_frac,
+            logits, pools, rows, _ = forward_paged(
+                p, cfg, toks, pools, None, tables, past,
+                chunk_mask(toks, lens), ts,
             )
+            return logits, pools, rows, None  # prefill observes no loads
 
-        self._prefill_paged = jax.jit(prefill_paged_fn, donate_argnums=5)
-
-        # speculative verify: chunk-of-k through the SAME chunked paged
-        # kernels, but keeping every chunk position's logits + the
-        # expert counts (models.decode_verify)
         def verify_paged_fn(p, toks, lens, past, tables, pools, ts):
-            mask = jnp.arange(toks.shape[1])[None, :] < lens[:, None]
-            return decode_verify(
-                p, cfg, toks, pools, tables, past, mask,
-                tiered=ts, cold_capacity_frac=cold_capacity_frac,
+            return forward_paged(
+                p, cfg, toks, pools, None, tables, past,
+                chunk_mask(toks, lens), ts, all_logits=True,
             )
 
+        self._step_paged = jax.jit(step_paged, donate_argnums=2)
+        self._prefill_paged = jax.jit(prefill_paged_fn, donate_argnums=5)
         self._verify_paged = jax.jit(verify_paged_fn, donate_argnums=5)
         self.prefill_rows = prefill_rows
         self.decode_table_widths = set()  # distinct sliced widths (pow2)
@@ -394,20 +348,6 @@ class TriMoEServingEngine:
             for li in range(len(self._layer_keys))
         }
 
-    # cache is owned by the SlotKVCache so the loop and engine share one
-    # source of truth; keep attribute-style access for legacy callers.
-    @property
-    def cache(self):
-        assert isinstance(self.kv, SlotKVCache), (
-            "raw-cache access is a SlotKVCache affordance; the paged "
-            "layout exposes kv.pools / kv.slot_state"
-        )
-        return self.kv.cache
-
-    @cache.setter
-    def cache(self, value):
-        self.kv.cache = value
-
     def _flatten_layer_keys(self) -> List[tuple]:
         """Ordered (kind, name, group) keys, one per MoE layer."""
         layers, slots, n_groups = moe_slot_names(self.cfg)
@@ -444,151 +384,21 @@ class TriMoEServingEngine:
         return out if key[0] == "layer" else tuple(a[key[2]] for a in out)
 
     # ----------------------------------------------------------- stepping
-    def step(self, tokens: jnp.ndarray, pos: int):
-        """Full-batch decode step + synchronous replan (legacy path)."""
-        logits, self.cache, counts = self._step(
-            self.params, tokens, self.cache, jnp.asarray(pos, jnp.int32), self.tiered
-        )
-        self._dispatched(int(np.prod(tokens.shape)))
-        self.stats.steps += 1
-        self.replan(counts, self.last_dispatch)
-        return logits
-
-    def step_slots(self, tokens, pos, slot_indices, live=None):
-        """Decode only the cache rows in `slot_indices` (the active
-        zigzag group): gather rows -> decode -> scatter back, all inside
-        one jit so the compile is reused across groups.
-
-        tokens: [W,1] int32; pos: [W] per-slot absolute positions;
-        live: optional [W] bool — dead (padded) rows are excluded from
-        MoE dispatch and expert counts so the predictor only sees real
-        loads. Returns (logits [W,V], expert_counts) WITHOUT replanning
-        — the serving loop replans from the previous group's counts
-        while this group's step is in flight (zigzag overlap), via
-        `replan`.
-        """
-        idx = jnp.asarray(slot_indices, jnp.int32)
-        if live is None:
-            live = jnp.ones((idx.shape[0],), bool)
-        logits, self.kv.cache, counts = self._step_slots(
-            self.params, jnp.asarray(tokens), self.kv.cache, idx,
-            jnp.asarray(pos, jnp.int32), self.tiered, jnp.asarray(live, bool),
-        )
-        self._dispatched(len(slot_indices))
-        self.stats.steps += 1
-        return logits, counts
-
-    def prefill_slots(self, prompts, slot_indices, lengths=None):
-        """Prefill newly admitted requests into their cache slots.
-
-        prompts: [W, S] int32; runs the full-sequence forward through
-        the tiered MoE runtime (engine params are stripped) and scatters
-        the resulting rows into the slot cache. Returns per-row logits
-        [W, V] — the first generated token.
-
-        Without `lengths`, every row is exactly S real tokens (legacy
-        exact-length path: one compile per distinct S). With `lengths`
-        [W], rows are RIGHT-padded to a shared bucket width S and run
-        through the MASKED prefill: pad keys masked out of attention,
-        recurrent states carry through pads, each row's cache written at
-        its true length, logits gathered at the last real token. Rows
-        are additionally padded up to `prefill_rows` (excess chunked),
-        so the jit only ever compiles (prefill_rows, bucket_width)
-        shapes — at most one compile per bucket-table entry
-        (`prefill_compiles`).
-        """
-        assert self.kv.seq_len is not None, (
-            "prefill_slots needs a SlotKVCache built with an explicit seq_len"
-        )
-        if lengths is None:
-            prompts = jnp.asarray(prompts, jnp.int32)
-            logits, sub_cache = self._prefill(
-                self.params, prompts, self.tiered, self.kv.seq_len
-            )
-            self.kv.scatter(sub_cache, slot_indices)
-            self.stats.prefills += prompts.shape[0]
-            self.stats.prefill_tokens += int(prompts.shape[0] * prompts.shape[1])
-            return logits
-
-        prompts = np.asarray(prompts, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        n, width = prompts.shape
-        assert len(slot_indices) == n and lengths.shape == (n,)
-        assert np.all(lengths <= width) and np.all(lengths > 0)
-        r = self.prefill_rows
-        out = []
-        for c0 in range(0, n, r):
-            nr = min(r, n - c0)
-            toks = np.zeros((r, width), np.int32)
-            lens = np.zeros((r,), np.int32)  # dummy rows: all-pad mask
-            toks[:nr] = prompts[c0:c0 + nr]
-            lens[:nr] = lengths[c0:c0 + nr]
-            logits, sub_cache = self._prefill_masked(
-                self.params, jnp.asarray(toks), jnp.asarray(lens),
-                self.tiered, self.kv.seq_len,
-            )
-            if nr < r:  # drop the dummy rows before scattering
-                sub_cache = gather_slots(sub_cache, list(range(nr)))
-            self.kv.scatter(sub_cache, list(slot_indices[c0:c0 + nr]))
-            out.append(logits[:nr])
-            self.stats.prefills += nr
-            self.stats.prefill_tokens += int(lens.sum())
-        return out[0] if len(out) == 1 else jnp.concatenate(out)
-
-    def _active_table_width(self, pos, live) -> int:
-        """Block-table columns decode actually needs this step (the
-        decode analogue of the prefill bucket bound — pow2 widths, at
-        most log2(blocks_per_slot) compiles per group width)."""
-        from repro.kernels.paged_attention import active_block_width
-
-        mx = int(pos[live].max()) if live.any() else 0
-        return active_block_width(
-            mx, self.kv.block_size, max(1, self.kv.blocks_per_slot)
-        )
-
     def step_slots_paged(self, tokens, pos, slot_indices, tables, live=None):
-        """Paged decode of the active zigzag group: recurrent state rows
-        gather/scatter by slot index as in `step_slots`, while attention
-        K/V reads and writes go through the shared block pools by each
-        row's block table (`tables` [W, nb] int32). The table is SLICED
-        to the pow2-bucketed active width first, so decode attention
-        (Pallas kernel or dense-gather ref) touches O(longest live row)
-        blocks instead of the full `blocks_per_slot` — positions beyond
-        a row's length were masked to exp(-inf) = 0 exactly, so the
-        slice is numerics-preserving. Returns (logits, expert_counts)
-        without replanning — see `step_slots`."""
-        assert isinstance(self.kv, PagedKVCache)
-        idx = jnp.asarray(slot_indices, jnp.int32)
-        live = (
-            np.ones((len(slot_indices),), bool) if live is None
-            else np.asarray(live, bool)
+        """Decode one token for each row of the active zigzag group.
+
+        tokens [W, 1] int32; pos [W] the rows' absolute positions;
+        tables [W, nb] int32 their block tables; live [W] bool (None =
+        all) — dead rows are excluded from MoE dispatch and expert
+        counts, so the predictor only sees real loads. Returns (logits
+        [W, V], expert_counts) WITHOUT replanning: the serving loop
+        replans from the previous group's counts while this step is in
+        flight (zigzag overlap), via `observe` / `plan_migrations`."""
+        n = len(slot_indices)
+        return self._run_paged(
+            "decode", np.asarray(tokens, np.int32), np.ones(n, np.int32),
+            np.asarray(pos, np.int32), slot_indices, live, tables,
         )
-        pos = np.asarray(pos, np.int64)
-        # dead rows still write their (garbage) K/V — point them at the
-        # trash block so a just-completed slot can never corrupt its own
-        # (possibly shared / radix-indexed) blocks before recycling
-        tables = np.array(tables, np.int32, copy=True)
-        tables[~live] = self.kv.trash
-        if self.kv.sanitizer is not None:
-            # the blocks this step's token writes actually land in: each
-            # row's table entry at its decode position (dead rows were
-            # just trash-routed above — validated on the real values)
-            lb = np.clip(pos // self.kv.block_size, 0, tables.shape[1] - 1)
-            self.kv.sanitizer.check_scatter_targets(
-                tables[np.arange(len(pos)), lb], live
-            )
-        width = self._active_table_width(pos, live)
-        self.decode_table_widths.add(width)
-        self._count_kv_walk(pos, width, 1)
-        tables = tables[:, :width]
-        logits, self.kv.pools, self.kv.slot_state, counts = self._step_paged(
-            self.params, jnp.asarray(tokens), self.kv.pools,
-            self.kv.slot_state, jnp.asarray(tables), idx,
-            jnp.asarray(pos, jnp.int32), self.tiered, jnp.asarray(live, bool),
-        )
-        self._dispatched(len(slot_indices))
-        self.stats.steps += 1
-        return logits, counts
 
     def prefill_slots_paged(self, suffixes, slot_indices, lengths, past_len):
         """Chunked suffix-only masked prefill into paged slots.
@@ -600,19 +410,12 @@ class TriMoEServingEngine:
         or earlier chunks otherwise). The rows' block tables must
         already cover prefix + suffix (PagedKVCache.admit_slot).
 
-        Block tables are SLICED to the pow2-bucketed active width
-        covering the furthest row end (prefix + suffix — the prefill
-        analogue of `step_slots_paged`'s decode slicing), so past-K/V
-        attention reads O(active blocks), not O(blocks_per_slot). Rows
-        are padded to `prefill_rows` (excess chunked) so the jit
-        compiles one (prefill_rows, bucket width, table width) shape —
-        at most len(bucket_table) x n_width_buckets(blocks_per_slot)
-        compiles (`prefill_compiles`, gated in CI).
+        Rows are padded to `prefill_rows` (more rows split into several
+        calls), so the jit compiles one (prefill_rows, bucket width,
+        table width) shape — at most len(bucket_table) x
+        n_width_buckets(blocks_per_slot) compiles (`prefill_compiles`).
         Returns per-row last-real-token logits [W, V].
         """
-        from repro.kernels.paged_attention import active_block_width
-
-        assert isinstance(self.kv, PagedKVCache)
         suffixes = np.asarray(suffixes, np.int32)
         lengths = np.asarray(lengths, np.int32)
         past_len = np.asarray(past_len, np.int32)
@@ -620,48 +423,13 @@ class TriMoEServingEngine:
         assert len(slot_indices) == n
         assert np.all(lengths > 0) and np.all(lengths <= width)
         r = self.prefill_rows
-        out = []
-        for c0 in range(0, n, r):
-            nr = min(r, n - c0)
-            end = int((past_len[c0:c0 + nr] + lengths[c0:c0 + nr]).max())
-            tw = active_block_width(
-                end - 1, self.kv.block_size, max(1, self.kv.blocks_per_slot)
+        out = [
+            self._run_paged(
+                "prefill", suffixes[c0:c0 + r], lengths[c0:c0 + r],
+                past_len[c0:c0 + r], list(slot_indices[c0:c0 + r]),
             )
-            self.prefill_table_widths.add(tw)
-            toks = np.zeros((r, width), np.int32)
-            lens = np.zeros((r,), np.int32)  # dummy rows: all-pad mask
-            past = np.zeros((r,), np.int32)
-            tables = np.full((r, tw), self.kv.trash, np.int32)
-            toks[:nr] = suffixes[c0:c0 + nr]
-            lens[:nr] = lengths[c0:c0 + nr]
-            past[:nr] = past_len[c0:c0 + nr]
-            tables[:nr] = self.kv.table_rows(slot_indices[c0:c0 + nr])[:, :tw]
-            if self.kv.sanitizer is not None:
-                # every block this chunk writes — the suffix span
-                # [past, past+len) of each real row — must be private;
-                # dummy pad rows must be all-trash
-                bs = self.kv.block_size
-                bids, mask = [], []
-                for j in range(r):
-                    lo, hi = int(past[j]) // bs, -(-int(past[j] + lens[j]) // bs)
-                    span = tables[j, lo:hi] if j < nr else tables[j]
-                    bids.extend(span.tolist())
-                    mask.extend([j < nr] * len(span))
-                self.kv.sanitizer.check_scatter_targets(bids, mask)
-            self._count_kv_walk(past + lens - 1, tw, width)
-            logits, self.kv.pools, row_states = self._prefill_paged(
-                self.params, jnp.asarray(toks), jnp.asarray(lens),
-                jnp.asarray(past), jnp.asarray(tables), self.kv.pools,
-                self.tiered,
-            )
-            if nr < r:  # drop the dummy rows before scattering state
-                row_states = gather_slots(row_states, list(range(nr)))
-            self.kv.slot_state = scatter_slots(
-                self.kv.slot_state, row_states, list(slot_indices[c0:c0 + nr])
-            )
-            out.append(logits[:nr])
-            self.stats.prefills += nr
-            self.stats.prefill_tokens += int(lens.sum())
+            for c0 in range(0, n, r)
+        ]
         return out[0] if len(out) == 1 else jnp.concatenate(out)
 
     def verify_slots_paged(self, chunks, slot_indices, lengths, past_len,
@@ -677,72 +445,108 @@ class TriMoEServingEngine:
         position (ServingLoop._spec_step) — rejected positions are
         rolled back afterwards via PagedKVCache.truncate.
 
-        Same compile accounting as decode/prefill: the chunk width pads
-        to pow2 (at most log2(k)+1 widths) and block tables slice to
-        the pow2 active width, so compiles are bounded by
-        n_chunk_widths x n_width_buckets (`verify_compiles`).
+        The chunk width pads to pow2 (at most log2(k)+1 widths), so
+        compiles are bounded by n_chunk_widths x n_width_buckets
+        (`verify_compiles`).
 
         Returns (logits [W, Kp, V], expert_counts) — position i's
         logits condition on chunk tokens 0..i and the cached prefix,
         bit-exact vs sequential decode in fp32."""
-        from repro.kernels.paged_attention import active_block_width
-
-        assert isinstance(self.kv, PagedKVCache)
         chunks = np.asarray(chunks, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        past_len = np.asarray(past_len, np.int32)
         n, width = chunks.shape
         assert len(slot_indices) == n
-        live = (
-            np.ones((n,), bool) if live is None else np.asarray(live, bool)
-        )
+        live = np.ones(n, bool) if live is None else np.asarray(live, bool)
+        lengths = np.where(live, np.asarray(lengths, np.int32), 0)
         assert np.all(lengths[live] > 0) and np.all(lengths <= width)
         kw = 1
         while kw < width:
             kw *= 2
+        self.verify_widths.add(kw)
         toks = np.zeros((n, kw), np.int32)
         toks[:, :width] = chunks
-        lens = np.where(live, lengths, 0).astype(np.int32)
-        past = np.where(live, past_len, 0).astype(np.int32)
-        end = int((past + lens).max()) if live.any() else 1
-        tw = active_block_width(
-            end - 1, self.kv.block_size, max(1, self.kv.blocks_per_slot)
-        )
-        self.verify_widths.add(kw)
-        self.verify_table_widths.add(tw)
-        # dead rows: all-trash tables + zero mask, like prefill pads
-        tables = np.full((n, tw), self.kv.trash, np.int32)
-        rows = self.kv.table_rows(slot_indices)[:, :tw]
-        tables[live] = rows[live]
-        if self.kv.sanitizer is not None:
-            # the chunk writes span [past, past+len) of each live row —
-            # every target block must be private; dead rows all-trash
-            bs = self.kv.block_size
+        past = np.where(live, np.asarray(past_len, np.int32), 0)
+        return self._run_paged("verify", toks, lengths.astype(np.int32),
+                               past.astype(np.int32), slot_indices, live)
+
+    def _run_paged(self, phase, toks, lens, past, slot_indices, live=None,
+                   tables=None):
+        """Prepare and run one paged forward call (`phase` is "decode",
+        "prefill" or "verify") for the rows of `slot_indices`.
+
+        toks [n, C] the rows' chunks; lens [n] each row's query count
+        (real tokens; a decode row always has one); past [n] tokens
+        cached before the chunk; live [n] (None = all) — dead rows are
+        routed to the trash block and keep their slot state; tables
+        [n, nb] the rows' block tables (None = `kv.table_rows`).
+        Prefill pads the rows to `prefill_rows` with dead rows.
+
+        Returns the call's logits, plus the expert counts for decode
+        and verify."""
+        from repro.kernels.paged_attention import active_block_width
+
+        kv, bs = self.kv, self.kv.block_size
+        n, chunk = toks.shape
+        rows = self.prefill_rows if phase == "prefill" else n
+        if tables is None:
+            tables = kv.table_rows(slot_indices)
+        live_rows = np.zeros(rows, bool)
+        live_rows[:n] = True if live is None else np.asarray(live, bool)
+
+        def pad(a):
+            return np.concatenate([a, np.zeros((rows - n,) + a.shape[1:], a.dtype)])
+
+        toks, lens, past = pad(toks), pad(lens), pad(past)
+        # the rows' last queries fix the table width the call reads: a
+        # pow2 bucket, so at most log2(blocks_per_slot) widths compile
+        last = past + lens - 1
+        mx = int(last[live_rows].max()) if live_rows.any() else 0
+        width = active_block_width(mx, bs, max(1, kv.blocks_per_slot))
+        {"decode": self.decode_table_widths,
+         "prefill": self.prefill_table_widths,
+         "verify": self.verify_table_widths}[phase].add(width)
+        # dead and pad rows still write their (garbage) K/V: they point
+        # at the trash block, so a just-completed slot can never corrupt
+        # its own (possibly shared / radix-indexed) blocks
+        tab = np.full((rows, width), kv.trash, np.int32)
+        tab[:n] = np.asarray(tables, np.int32)[:, :width]
+        tab[~live_rows] = kv.trash
+        if kv.sanitizer is not None:
+            # every block the call writes — [past, past + len) of each
+            # live row — must be private; dead rows must be all-trash
             bids, mask = [], []
-            for j in range(n):
-                if live[j]:
-                    lo = int(past[j]) // bs
-                    hi = -(-int(past[j] + lens[j]) // bs)
-                    span = tables[j, lo:hi]
-                else:
-                    span = tables[j]
+            for j in range(rows):
+                span = (tab[j, past[j] // bs:-(-(past[j] + lens[j]) // bs)]
+                        if live_rows[j] else tab[j])
                 bids.extend(span.tolist())
-                mask.extend([bool(live[j])] * len(span))
-            self.kv.sanitizer.check_scatter_targets(bids, mask)
-        self._count_kv_walk(past + lens - 1, tw, kw)
-        logits, self.kv.pools, row_states, counts = self._verify_paged(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(past), jnp.asarray(tables), self.kv.pools,
-            self.tiered,
-        )
-        live_rows = [j for j in range(n) if live[j]]
-        if live_rows:  # dead rows must not clobber their slot state
-            sub = gather_slots(row_states, live_rows)
-            self.kv.slot_state = scatter_slots(
-                self.kv.slot_state, sub,
-                [int(slot_indices[j]) for j in live_rows],
+                mask.extend([bool(live_rows[j])] * len(span))
+            kv.sanitizer.check_scatter_targets(bids, mask)
+        self._count_kv_walk(last, width, chunk)
+        args = (self.params, jnp.asarray(toks))
+        if phase == "decode":
+            idx = jnp.asarray(slot_indices, jnp.int32)
+            logits, kv.pools, row_states, counts = self._step_paged(
+                *args, kv.pools, kv.slot_state, jnp.asarray(tab), idx,
+                jnp.asarray(past), self.tiered, jnp.asarray(live_rows),
             )
-        self._dispatched(n * kw)
+        else:
+            fn = self._prefill_paged if phase == "prefill" else self._verify_paged
+            logits, kv.pools, row_states, counts = fn(
+                *args, jnp.asarray(lens), jnp.asarray(past), jnp.asarray(tab),
+                kv.pools, self.tiered,
+            )
+        keep = np.flatnonzero(live_rows)
+        if len(keep) and jax.tree.leaves(row_states):
+            # recurrent state: live rows only, so a dead row's garbage
+            # never lands in its slot
+            kv.slot_state = scatter_slots(
+                kv.slot_state, gather_slots(row_states, keep),
+                [int(slot_indices[j]) for j in keep],
+            )
+        if phase == "prefill":
+            self.stats.prefills += n
+            self.stats.prefill_tokens += int(lens.sum())
+            return logits[:n]
+        self._dispatched(rows * chunk)
         self.stats.steps += 1
         return logits, counts
 
@@ -755,16 +559,11 @@ class TriMoEServingEngine:
 
     @property
     def prefill_compiles(self) -> int:
-        """Distinct jit compiles of the bucketed masked prefill across
-        BOTH variants — the contiguous slot path (bounded by
-        len(bucket_table)) and the paged/chunked path (bounded by
+        """Distinct jit compiles of the paged prefill — bounded by
         len(bucket_table) x n_width_buckets(blocks_per_slot), the
-        table-width slicing factor) — the quantity the CI compile-count
+        table-width slicing factor; the quantity the CI compile-count
         gate bounds (benchmarks/serving_bench.py)."""
-        return int(
-            self._prefill_masked._cache_size()
-            + self._prefill_paged._cache_size()
-        )
+        return int(self._prefill_paged._cache_size())
 
     # ---------------------------------------------------------- migration
     def _tier_cost(self, tier: int, load: float) -> float:
@@ -850,7 +649,7 @@ class TriMoEServingEngine:
         st.moe_assignments += int(held.sum())
         st.experts_touched += int(np.count_nonzero(held))
         st.expert_rows += sum(
-            n * tier_capacity(tid, dispatch.tokens, top_k, self.cold_capacity_frac)
+            n * tier_capacity(tid, dispatch.tokens, top_k, 1.0)
             for tid, n in enumerate(self._tier_slots)
         )
         by_tier = np.bincount(dispatch.tier.ravel(), weights=counts.ravel(),
@@ -1033,12 +832,10 @@ class TriMoEServingEngine:
 
     def replan(self, counts: np.ndarray,
                dispatch: Optional[MoEDispatch] = None) -> None:
-        """Legacy synchronous path: observe + plan + apply in one call
-        (`engine.step` and pre-PR-7 callers)."""
+        """Synchronous observe + plan + apply in one call (the serving
+        loop instead overlaps them with the next step)."""
         self.observe(counts, dispatch)
         self.apply_planned(self.plan_migrations())
-
-    _replan = replan  # legacy name
 
 
 def _swapped(tables: tuple, plans: list) -> tuple:

@@ -4,9 +4,9 @@ The model's cache pytrees (models.model.init_cache) are ring buffers of
 static length; this module adds the bookkeeping the engine needs:
 abstract (allocation-free) cache specs for the dry-run, per-arch byte
 accounting (the paper offloads the "large KV cache ... to host DIMMs",
-§4.1 — on TPU it stays HBM-resident but seq-sharded), slot reset for
-request recycling, and the slot-managed cache the continuous-batching
-serving loop allocates requests into.
+§4.1 — on TPU it stays HBM-resident but seq-sharded), and the row
+gather / scatter / reset the paged layout's per-slot state uses
+(serving/paged_kv.py).
 
 Cache structure convention (init_cache): top-level keys are "layer<i>"
 (unrolled prefix layers; leaves carry the batch/slot dim on axis 0) and
@@ -15,8 +15,6 @@ the batch/slot dim on axis 1). All row-level operations here (gather /
 scatter / reset) respect that split.
 """
 from __future__ import annotations
-
-from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -78,70 +76,3 @@ def reset_slots(cache, slot_indices):
         k: jax.tree.map(lambda a, ax=_batch_axis(k): zero(a, ax), v)
         for k, v in cache.items()
     }
-
-
-def _infer_n_slots(cache) -> int:
-    for k, v in cache.items():
-        leaves = jax.tree.leaves(v)
-        if leaves:
-            return int(leaves[0].shape[_batch_axis(k)])
-    raise ValueError("empty cache pytree")
-
-
-class SlotKVCache:
-    """Slot-managed decode cache: a fixed pool of `n_slots` ring-buffer
-    rows plus a free-list, so the serving loop can admit a request into
-    any free row and evict it (zeroing the row) on completion.
-
-    Owns the cache pytree; the serving engine reads/writes `.cache`
-    through gather/scatter so only the active group's rows move.
-    """
-
-    def __init__(self, cfg: ModelConfig, n_slots: int, seq_len: int):
-        self.cache = init_cache(cfg, n_slots, seq_len)
-        self.n_slots = n_slots
-        self.seq_len: Optional[int] = seq_len
-        self._free: List[int] = list(range(n_slots))
-
-    @classmethod
-    def from_cache(cls, cache, seq_len: Optional[int] = None) -> "SlotKVCache":
-        """Wrap an externally built cache pytree (legacy engine path).
-        All slots start allocated — the caller composed the batch itself."""
-        self = cls.__new__(cls)
-        self.cache = cache
-        self.n_slots = _infer_n_slots(cache)
-        self.seq_len = seq_len
-        self._free = []
-        return self
-
-    @property
-    def n_free(self) -> int:
-        return len(self._free)
-
-    def allocate(self) -> Optional[int]:
-        """Claim a free slot id, or None when the pool is exhausted."""
-        return self._free.pop(0) if self._free else None
-
-    def claim(self, slot: int) -> None:
-        """Claim a specific free slot (external allocator, e.g. the
-        ZigzagBatcher picking the slot, with this cache mirroring it)."""
-        assert slot in self._free, f"slot {slot} is not free"
-        self._free.remove(slot)
-
-    def free(self, slot_indices: Sequence[int]) -> None:
-        """Evict finished requests: zero their rows and recycle the ids."""
-        slots = [int(s) for s in slot_indices]
-        if not slots:
-            return
-        taken = set(self._free)
-        dup = [s for s in slots if s in taken or not 0 <= s < self.n_slots]
-        assert not dup, f"double free / out of range: {dup}"
-        assert len(set(slots)) == len(slots), f"duplicate slots in free: {slots}"
-        self.cache = reset_slots(self.cache, slots)
-        self._free.extend(slots)
-
-    def gather(self, slot_indices):
-        return gather_slots(self.cache, slot_indices)
-
-    def scatter(self, sub_cache, slot_indices) -> None:
-        self.cache = scatter_slots(self.cache, sub_cache, slot_indices)

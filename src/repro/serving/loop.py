@@ -9,22 +9,23 @@ engine:
 
   ServingLoop
     ├─ ZigzagBatcher   — request queue, slot allocation, group rotation
-    ├─ SlotKVCache     — slot-managed ring-buffer cache rows
-    └─ TriMoEServingEngine — jitted tiered decode / prefill / migration
+    ├─ PagedKVCache    — block pools, block tables, radix prefix index
+    └─ TriMoEServingEngine — one jitted paged forward (decode, prefill
+                             chunks, verify) and the tier migrations
 
-Per iteration: (1) recycle finished slots (evicting their cache rows)
-and admit queued requests — admissions sharing a prompt-length bucket
-are padded to the bucket width and prefilled in ONE masked prefill
-call that writes each row's cache at its true length and samples the
-first token from the per-row last-real-token logits; (2) decode the
-active zigzag group at its per-slot positions (fixed group width —
-dead slots are masked, so the decode step compiles once); (3) while
+Per iteration: (1) recycle finished slots (indexing their blocks for
+prefix reuse) and admit queued requests, whose uncached prompt
+suffixes stream into the cache in budgeted prefill chunks (or, for
+recurrent archs, one masked prefill call per prompt-length bucket);
+the last chunk's last-real-token logits sample the first token; (2)
+decode the active zigzag group at its per-slot positions (fixed group
+width — dead slots are masked, so the decode step compiles once); (3) while
 that step is in flight on the device, the host replans expert
 migrations from the PREVIOUS group's realized loads — the zigzag
 overlap of migration and compute; (4) record sampled tokens and rotate
 to the next group.
 
-Decoding is greedy and, with the engine default cold_capacity_frac=1.0,
+Decoding is greedy and, with the tiered MoE dispatch dropless,
 token-for-token identical to single-request generation (verified in
 tests/test_serving_loop.py).
 """
@@ -49,7 +50,6 @@ from repro.serving.engine import (
     fill_tiers_from_params,
     init_tiered_for_model,
 )
-from repro.serving.kv_cache import SlotKVCache
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.tiered_moe import TierSizes, tier_sizes
 
@@ -215,25 +215,24 @@ class _PrefillTask:
 class ServingLoop:
     """Multi-request continuous-batching loop over the TriMoE engine.
 
-    batch_size decode slots are split into n_groups zigzag groups; the
-    cache holds batch_size rows of length cache_len (each admitted
-    request needs prompt_len + max_new_tokens - 1 <= cache_len to avoid
-    ring wrap-around).
+    batch_size decode slots are split into n_groups zigzag groups; each
+    slot's block table covers cache_len tokens (each admitted request
+    needs prompt_len + max_new_tokens - 1 <= cache_len).
 
     Prefill is LENGTH-BUCKETED by default: `bucket_table` (default
     powers-of-two widths capped at cache_len) pads every admitted
     prompt to its bucket width and batches same-bucket admissions into
     one masked prefill call of up to `prefill_rows` rows, so a
     mixed-length trace compiles the prefill at most len(bucket_table)
-    times (engine.prefill_compiles; gated in CI via
+    times per table width (engine.prefill_compiles; gated in CI via
     benchmarks/serving_bench.py --mixed). Pass bucket_table=None for
-    the legacy exact-length path (one compile per distinct prompt
-    length). `max_admit_wait` caps how many admit rounds a partial
-    same-bucket cohort may be held back (starvation cap).
+    exact widths (one compile per distinct suffix length).
+    `max_admit_wait` caps how many admit rounds a partial same-bucket
+    cohort may be held back (starvation cap).
 
-    The KV store is PAGED by default (`kv_layout="paged"`,
-    serving/paged_kv.py): K/V lives in a pool of `block_size`-token
-    blocks addressed through per-slot block tables, admission claims
+    The KV store is PAGED (serving/paged_kv.py): K/V lives in a pool
+    of `block_size`-token blocks addressed through per-slot block
+    tables, admission claims
     the longest radix-cached prefix of each prompt (`prefix_cache`) and
     prefills only the uncached suffix (still bucketed + masked), decode
     allocates blocks on demand, and eviction returns refcount-0 blocks
@@ -241,25 +240,25 @@ class ServingLoop:
     `kv_pool_blocks` shrinks the pool below the contiguous reservation
     (`batch_size * ceil(cache_len / block_size)`); the HBM thereby
     reclaimed feeds `tiered_moe.tier_sizes(reclaimed_kv_bytes=...)` —
-    more hot-resident experts. `kv_layout="slots"` restores the
-    contiguous SlotKVCache.
+    more hot-resident experts.
 
-    Attention against the pools is BLOCK-SPARSE in BOTH phases: the
-    engine slices each decode step's AND each prefill chunk's block
-    tables to the pow2-bucketed active width, and `paged_attn_backend`
-    ("auto" | "pallas" | "ref", default the config's setting) picks the
-    chunked Pallas paged-attention kernel family
-    (kernels/paged_attention — decode is the chunk-of-1 case) or the
-    jnp dense-gather path. `moe_backend` is the same knob for the
-    expert-FFN hot path (kernels/moe_gemm grouped GEMM for prefill
-    buffers, kernels/expert_gemv batched GEMV for decode buffers, or
-    the einsum reference); both resolve through the one
+    Decode steps, prefill chunks and verify chunks are one paged forward
+    pass (models.model.forward_paged; decode is the chunk of 1), and
+    attention against the pools is BLOCK-SPARSE: the engine slices each
+    call's block tables to the pow2-bucketed active width, and
+    `paged_attn_backend` ("auto" | "pallas" | "ref", default the
+    config's setting) picks the chunked Pallas paged-attention kernel
+    (kernels/paged_attention) or the jnp dense-gather path.
+    `moe_backend` is the same knob for the expert-FFN hot path
+    (kernels/moe_gemm grouped GEMM for prefill buffers,
+    kernels/expert_gemv batched GEMV for decode buffers, or the einsum
+    reference); both resolve through the one
     `kernels/backend.py` rule and land in the config the engine's
     jitted step closures capture.
 
     Admission prefill is CHUNKED and PIGGYBACKED by default
-    (`chunked_prefill=True`, paged layout + attention-only archs): an
-    admitted prompt's uncached suffix streams into the cache in chunks
+    (`chunked_prefill=True`, attention-only archs): an admitted
+    prompt's uncached suffix streams into the cache in chunks
     of at most `prefill_chunk_tokens` tokens per loop iteration (chunk
     widths drawn from the bucket table, past-widths from the same pow2
     table slicing as decode), each chunk sharing the iteration with a
@@ -268,8 +267,7 @@ class ServingLoop:
     `serving_bench.py --mixed` measures). The slot joins decode once
     its last chunk lands and samples the first token. Recurrent-mixer
     archs (chunk state cannot be threaded through a token-keyed cache)
-    and the contiguous `kv_layout="slots"` fall back to whole-suffix
-    admission prefill.
+    fall back to whole-prompt admission prefill.
 
     SPECULATIVE DECODE (`spec_decode=True`, serving/spec_decode.py):
     each decode step drafts up to `spec_config.k` tokens per live slot
@@ -285,8 +283,8 @@ class ServingLoop:
     wider chunks agree to fp32 rounding with exactly equal argmax
     tokens; throughput multiplies by the acceptance rate on
     repetitive/replayed traffic.
-    Requires the paged layout + an attention-only arch (same gate as
-    chunked prefill). Acceptance stats land on the shared registry
+    Requires an attention-only arch (same gate as chunked prefill).
+    Acceptance stats land on the shared registry
     (`serving.spec_*`, `serving.spec_acceptance_rate`).
 
     OBSERVABILITY (repro.obs): `obs=` accepts an `Observability` (share
@@ -311,12 +309,10 @@ class ServingLoop:
         sizes: Optional[TierSizes] = None,
         plan_size: Optional[int] = None,  # DEPRECATED -> scheduler=
         thresholds: Optional[TierThresholds] = None,  # DEPRECATED -> scheduler=
-        cold_capacity_frac: float = 1.0,
         rng_seed: int = 1,
         bucket_table: "BucketTable | None | str" = "auto",
         prefill_rows: Optional[int] = None,
         max_admit_wait: int = 4,
-        kv_layout: str = "paged",
         block_size: int = 4,
         kv_pool_blocks: Optional[int] = None,
         prefix_cache: bool = True,
@@ -331,7 +327,6 @@ class ServingLoop:
         obs=None,  # Observability | ObsConfig | None (repro.obs)
     ):
         assert cfg.moe is not None, "ServingLoop drives the TriMoE MoE path"
-        assert kv_layout in ("paged", "slots"), kv_layout
         if paged_attn_backend is not None:
             cfg = dataclasses.replace(cfg, paged_attn_backend=paged_attn_backend)
         if moe_backend is not None:
@@ -353,31 +348,22 @@ class ServingLoop:
         self.obs = resolve_obs(cfg, obs, caller="ServingLoop")
         self._tr = self.obs.tracer
         self.cfg = cfg
-        self.paged = kv_layout == "paged"
         from repro.serving.paged_kv import prefix_cacheable
 
         # chunked piggyback needs a token-position-addressable cache for
         # EVERY mixer (a chunk boundary cannot thread recurrent state)
-        self.chunked = (
-            chunked_prefill and self.paged and prefix_cacheable(cfg)
+        self.chunked = chunked_prefill and prefix_cacheable(cfg)
+        self.kv = PagedKVCache(
+            cfg, batch_size, cache_len, block_size=block_size,
+            n_blocks=kv_pool_blocks, prefix_cache=prefix_cache,
+            sanitize=kv_sanitize,
         )
-        if self.paged:
-            self.kv = PagedKVCache(
-                cfg, batch_size, cache_len, block_size=block_size,
-                n_blocks=kv_pool_blocks, prefix_cache=prefix_cache,
-                sanitize=kv_sanitize,
-            )
-            reclaimed = self.kv.reclaimed_bytes(cache_len)
-        else:
-            self.kv = SlotKVCache(cfg, batch_size, cache_len)
-            reclaimed = 0
         if tiered is None:
             import jax
 
             if sizes is None:
-                sizes = (
-                    tier_sizes(cfg, reclaimed_kv_bytes=reclaimed)
-                    if self.paged else _default_sizes(cfg)
+                sizes = tier_sizes(
+                    cfg, reclaimed_kv_bytes=self.kv.reclaimed_bytes(cache_len)
                 )
             tiered = init_tiered_for_model(jax.random.PRNGKey(rng_seed), cfg, sizes)
             tiered = fill_tiers_from_params(params, tiered, cfg)
@@ -390,7 +376,6 @@ class ServingLoop:
         )
         self.engine = TriMoEServingEngine(
             cfg, params, self.kv, tiered, sizes=sizes,
-            cold_capacity_frac=cold_capacity_frac,
             prefill_rows=prefill_rows or min(batch_size, 4),
             scheduler=self.policy, obs=self.obs,
         )
@@ -403,15 +388,15 @@ class ServingLoop:
         assert prefill_chunk_tokens >= 1
         self.prefill_chunk_tokens = prefill_chunk_tokens
         # speculative multi-token decode: verify-through-the-chunked-
-        # kernels needs the paged layout and a token-position-
-        # addressable cache for every mixer (same gate as chunked
-        # prefill — truncate cannot roll back recurrent state)
+        # kernels needs a token-position-addressable cache for every
+        # mixer (same gate as chunked prefill — truncate cannot roll
+        # back recurrent state)
         self.spec = bool(spec_decode)
         if self.spec:
-            assert self.paged and prefix_cacheable(cfg), (
-                "spec_decode requires kv_layout='paged' and an "
-                "attention-only arch (chunk-of-k verification and "
-                "rollback go through the paged block pools)"
+            assert prefix_cacheable(cfg), (
+                "spec_decode requires an attention-only arch (chunk-of-k "
+                "verification and rollback go through the paged block "
+                "pools)"
             )
             from repro.serving.spec_decode import PromptLookupDrafter
 
@@ -425,7 +410,7 @@ class ServingLoop:
         self._t_admit: Dict[int, float] = {}
         self._t_submit: Dict[int, float] = {}
         self._t_last_tok: Dict[int, float] = {}
-        self._slot_req: Dict[int, Request] = {}  # paged: slot -> request
+        self._slot_req: Dict[int, Request] = {}  # slot -> request
         self._prefill_tasks: List[_PrefillTask] = []  # FIFO piggyback queue
         # previous group's realized loads and its engine.last_dispatch
         self._pending_counts = None
@@ -444,14 +429,9 @@ class ServingLoop:
         self.batcher.submit(req)
 
     def _free_slots(self, freed: List[int]) -> None:
-        """Evict finished requests' KV: paged slots index their full
-        (prompt + generated) blocks for future prefix hits before the
-        refcounts drop; contiguous slots zero their rows."""
-        if not freed:
-            return
-        if not self.paged:
-            self.kv.free(freed)
-            return
+        """Evict finished requests' KV: slots index their full (prompt +
+        generated) blocks for future prefix hits before the refcounts
+        drop."""
         for i in freed:
             r = self._slot_req.pop(i, None)
             if self.drafter is not None:
@@ -485,15 +465,12 @@ class ServingLoop:
         past_len: Dict[int, int] = {}
         for i in filled:
             r = self.batcher.slots[i].request
-            if self.paged:
-                # prefix-match on admission: claim the longest cached
-                # prefix, allocate fresh blocks for the uncached rest
-                past_len[i] = self.kv.admit_slot(i, r.prompt)
-                self._slot_req[i] = r
-                if self.drafter is not None:
-                    self.drafter.begin_slot(i, r.prompt)
-            else:
-                self.kv.claim(i)
+            # prefix-match on admission: claim the longest cached
+            # prefix, allocate fresh blocks for the uncached rest
+            past_len[i] = self.kv.admit_slot(i, r.prompt)
+            self._slot_req[i] = r
+            if self.drafter is not None:
+                self.drafter.begin_slot(i, r.prompt)
             self._t_admit[r.rid] = time.time()
             self.stats.admitted += 1
         if not filled:
@@ -509,19 +486,11 @@ class ServingLoop:
                     _PrefillTask(i, r, past_len.get(i, 0))
                 )
             return
-        # prefill writes the slots' cache (rows or blocks) in place; the
-        # per-row logits sample the first generated token (no wasted
-        # re-decode of the last prompt token). Prompt-token accounting
-        # lives in engine.stats.prefill_tokens.
-        if not self.paged and self.bucket_table is None:
-            for i in filled:  # legacy exact-length path
-                r = self.batcher.slots[i].request
-                self._prefill_dispatched([r])
-                logits = self.engine.prefill_slots(r.prompt[None, :], [i])
-                self._record_first(r, logits[0])
-            return
-        # batch same-bucket admissions into one padded masked prefill;
-        # under the paged layout rows are keyed by their UNCACHED suffix
+        # prefill writes the slots' blocks in place; the per-row logits
+        # sample the first generated token (no wasted re-decode of the
+        # last prompt token). Prompt-token accounting lives in
+        # engine.stats.prefill_tokens. Same-bucket admissions batch into
+        # one padded masked prefill, rows keyed by their UNCACHED suffix
         # length — a prefix hit moves the request to a smaller bucket
         groups: Dict[int, List[int]] = {}
         for i in filled:
@@ -544,16 +513,13 @@ class ServingLoop:
                 lengths[row] = len(suffix)
             self._prefill_dispatched(
                 [self.batcher.slots[i].request for i in slots])
-            if self.paged:
-                logits = self.engine.prefill_slots_paged(
-                    prompts, slots, lengths, pasts
-                )
-                for i in slots:
-                    # index the freshly computed prompt blocks so later
-                    # (and queued) admissions can share them
-                    self.kv.commit_prompt(i, self.batcher.slots[i].request.prompt)
-            else:
-                logits = self.engine.prefill_slots(prompts, slots, lengths=lengths)
+            logits = self.engine.prefill_slots_paged(
+                prompts, slots, lengths, pasts
+            )
+            for i in slots:
+                # index the freshly computed prompt blocks so later
+                # (and queued) admissions can share them
+                self.kv.commit_prompt(i, self.batcher.slots[i].request.prompt)
             for row, i in enumerate(slots):
                 self._record_first(
                     self.batcher.slots[i].request, logits[row], slot=i
@@ -725,19 +691,14 @@ class ServingLoop:
                     self._spec_step(idxs, toks, pos, live)
                 return
             with tr.span("serving.decode"):
-                if self.paged:
-                    for row, i in enumerate(idxs):
-                        if live[row]:
-                            # on-demand block alloc at block boundaries,
-                            # copy-on-write if the tail block is shared
-                            self.kv.ensure_block(i, int(pos[row]))
-                    logits, counts = self.engine.step_slots_paged(
-                        toks, pos, idxs, self.kv.table_rows(idxs), live=live
-                    )
-                else:
-                    logits, counts = self.engine.step_slots(
-                        toks, pos, idxs, live=live
-                    )
+                for row, i in enumerate(idxs):
+                    if live[row]:
+                        # on-demand block alloc at block boundaries,
+                        # copy-on-write if the tail block is shared
+                        self.kv.ensure_block(i, int(pos[row]))
+                logits, counts = self.engine.step_slots_paged(
+                    toks, pos, idxs, self.kv.table_rows(idxs), live=live
+                )
                 # zigzag overlap: while this group's step runs on the
                 # device, the host applies + replans migrations from
                 # previous loads
@@ -878,10 +839,3 @@ class ServingLoop:
         self.stats.wall_s += time.time() - t_start
         return self.completions
 
-
-def _default_sizes(cfg: ModelConfig) -> TierSizes:
-    """Example-scale tier split: ~25% hot, ~30% warm, rest cold."""
-    e = cfg.moe.n_experts
-    n_hot = max(1, e // 4)
-    n_warm = max(1, int(0.3 * e))
-    return TierSizes(n_hot, n_warm, e - n_hot - n_warm)

@@ -1,7 +1,7 @@
 """Paged KV cache with radix prefix reuse — the HBM-reclaim pillar.
 
-`SlotKVCache` reserves one contiguous max-seq strip per slot, so memory
-is committed at admission for tokens that may never be generated and
+A contiguous cache reserves one max-seq strip per slot, so memory is
+committed at admission for tokens that may never be generated and
 identical prompt prefixes (system prompts, few-shot headers) are stored
 — and recomputed — once per request. This module replaces that with a
 vLLM/SGLang-style paged layout:
@@ -328,7 +328,7 @@ class PagedKVCache:
     # ------------------------------------------------------- accounting
     @property
     def n_free(self) -> int:
-        """Free SLOTS (SlotKVCache-compatible semantics)."""
+        """Free slots."""
         return len(self._slot_free)
 
     @property
@@ -354,8 +354,8 @@ class PagedKVCache:
         )
 
     def reclaimed_bytes(self, cache_len: int) -> int:
-        """HBM the paged layout hands back vs the contiguous SlotKVCache
-        at the same slot count — the budget `tier_sizes` converts into
+        """HBM the paged layout hands back vs a contiguous cache
+        (`kv_cache.cache_bytes`) at the same slot count — the budget `tier_sizes` converts into
         extra hot-resident experts. Never negative (a pool LARGER than
         the contiguous reservation reclaims nothing), and exactly 0 for
         a zero/negative `cache_len` (there is no contiguous layout to
@@ -569,11 +569,6 @@ class PagedKVCache:
         self._slot_free.append(slot)
         if self.sanitizer is not None:
             self.sanitizer.validate("free_slot")
-
-    def free(self, slot_indices: Sequence[int]) -> None:
-        """SlotKVCache-compatible eviction (no token indexing)."""
-        for s in slot_indices:
-            self.free_slot(int(s))
 
     # ---------------------------------------------------------- views
     def table_rows(self, slot_indices) -> np.ndarray:

@@ -1,6 +1,6 @@
 """Unit tests: ZigzagBatcher composition logic (FIFO and bucket-aware
 admission with the starvation cap), the BucketTable policy, and the
-slot-managed KV cache (gather/scatter/reset + byte accounting)."""
+per-slot cache row helpers (gather/scatter/reset + byte accounting)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +10,6 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.models.model import init_cache
 from repro.serving.batching import BucketTable, Request, ZigzagBatcher
 from repro.serving.kv_cache import (
-    SlotKVCache,
     cache_bytes,
     gather_slots,
     reset_slots,
@@ -227,24 +226,3 @@ def test_gather_scatter_roundtrip(smoke_cfg):
             rows = np.asarray(jnp.moveaxis(leaf, ax, 0))
             assert np.all(rows[2] == 3) and np.all(rows[0] == 1)
             assert not rows[1].any() and not rows[3].any()
-
-
-def test_slot_kv_cache_alloc_claim_free(smoke_cfg):
-    kv = SlotKVCache(smoke_cfg, 3, 8)
-    assert kv.n_free == 3
-    assert kv.allocate() == 0
-    kv.claim(2)
-    assert kv.n_free == 1
-    with pytest.raises(AssertionError):
-        kv.claim(2)  # already taken
-    kv.cache = jax.tree.map(jnp.ones_like, kv.cache)
-    kv.free([2])
-    # freed row zeroed, others untouched
-    leaf = jax.tree.leaves(kv.cache["stack"])[0]
-    assert not np.asarray(leaf[:, 2]).any() and np.asarray(leaf[:, 0]).all()
-    with pytest.raises(AssertionError):
-        kv.free([2])  # double free
-    with pytest.raises(AssertionError):
-        kv.free([0, 0])  # duplicate ids within one call
-    assert sorted([kv.allocate(), kv.allocate()]) == [1, 2]
-    assert kv.allocate() is None  # exhausted

@@ -12,8 +12,8 @@ Evidence layers:
     deterministic sweeps run in the FAST tier so CPU CI always
     exercises the Pallas path in interpret mode;
   * backend dispatch: "auto" off-TPU resolves to ref, "pallas" off-TPU
-    interprets, and model-level gqa/mla_decode_paged agree across
-    backends;
+    interprets, and model-level gqa/mla_prefill_paged at chunk 1 (a
+    decode step) agree across backends;
   * model level: chunked paged prefill (split at arbitrary chunk
     boundaries) is token-identical to the contiguous full-sequence
     `prefill`;
@@ -144,12 +144,9 @@ def test_model_gqa_decode_paged_backends_agree():
     pk = jnp.asarray(rng.normal(size=(n_blocks + 1, bs, kv, hd)), jnp.float32)
     pv = jnp.asarray(rng.normal(size=(n_blocks + 1, bs, kv, hd)), jnp.float32)
     pos = np.asarray([3, 11], np.int32)
-    o_ref, k_ref, v_ref = attn.gqa_decode_paged(
-        p, cfg, x, pk, pv, jnp.asarray(tables), pos, backend="ref"
-    )
-    o_pal, k_pal, v_pal = attn.gqa_decode_paged(
-        p, cfg, x, pk, pv, jnp.asarray(tables), pos, backend="pallas"
-    )
+    args = (p, cfg, x, pk, pv, jnp.asarray(tables), pos, pos[:, None], None)
+    o_ref, k_ref, v_ref = attn.gqa_prefill_paged(*args, backend="ref")
+    o_pal, k_pal, v_pal = attn.gqa_prefill_paged(*args, backend="pallas")
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(k_pal), np.asarray(k_ref))
@@ -169,12 +166,9 @@ def test_model_mla_decode_paged_backends_agree():
     pr = jnp.asarray(rng.normal(size=(n_blocks + 1, bs, m.qk_rope_head_dim)),
                      jnp.float32)
     pos = np.asarray([2, 10], np.int32)
-    o_ref, _, _ = attn.mla_decode_paged(
-        p, cfg, x, pc, pr, jnp.asarray(tables), pos, backend="ref"
-    )
-    o_pal, _, _ = attn.mla_decode_paged(
-        p, cfg, x, pc, pr, jnp.asarray(tables), pos, backend="pallas"
-    )
+    args = (p, cfg, x, pc, pr, jnp.asarray(tables), pos, pos[:, None], None)
+    o_ref, _, _ = attn.mla_prefill_paged(*args, backend="ref")
+    o_pal, _, _ = attn.mla_prefill_paged(*args, backend="pallas")
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -472,7 +466,7 @@ def test_model_prefill_paged_chunked_equals_contiguous_prefill(serve_setup):
     (mid-block) boundary yields the same last-token logits as the
     single-call paged prefill AND as the contiguous full-sequence
     `prefill` — the unified-path invariant behind piggyback chunking."""
-    from repro.models.model import prefill, prefill_paged
+    from repro.models.model import forward_paged, prefill
     from repro.serving.paged_kv import PagedKVCache
 
     cfg, params = serve_setup
@@ -492,10 +486,10 @@ def test_model_prefill_paged_chunked_equals_contiguous_prefill(serve_setup):
         pools, logits = kv.pools, None
         bounds = [0, *splits, plen]
         for lo, hi in zip(bounds, bounds[1:]):
-            logits, pools, _ = prefill_paged(
-                params, cfg, {"tokens": jnp.asarray(prompt[None, lo:hi])},
-                pools, tables, jnp.asarray([lo], jnp.int32),
-                jnp.ones((1, hi - lo), bool),
+            logits, pools, _, _ = forward_paged(
+                params, cfg, jnp.asarray(prompt[None, lo:hi]), pools, None,
+                tables, jnp.asarray([lo], jnp.int32),
+                jnp.ones((1, hi - lo), bool), None,
             )
         return logits
 
@@ -515,7 +509,7 @@ def test_model_mla_prefill_paged_chunked_matches_contiguous():
     contiguous `prefill` (argmax-identical; absolute tolerance at the
     arch's bf16 absorbed-vs-expanded level) and chunk splitting is
     exactly stable."""
-    from repro.models.model import init_params, prefill, prefill_paged
+    from repro.models.model import forward_paged, init_params, prefill
     from repro.serving.paged_kv import PagedKVCache
 
     cfg = reduce_for_smoke(get_config(MLA_ARCH))
@@ -535,10 +529,10 @@ def test_model_mla_prefill_paged_chunked_matches_contiguous():
         pools, logits = kv.pools, None
         bounds = [0, *splits, plen]
         for lo, hi in zip(bounds, bounds[1:]):
-            logits, pools, _ = prefill_paged(
-                params, cfg, {"tokens": jnp.asarray(prompt[None, lo:hi])},
-                pools, tables, jnp.asarray([lo], jnp.int32),
-                jnp.ones((1, hi - lo), bool),
+            logits, pools, _, _ = forward_paged(
+                params, cfg, jnp.asarray(prompt[None, lo:hi]), pools, None,
+                tables, jnp.asarray([lo], jnp.int32),
+                jnp.ones((1, hi - lo), bool), None,
             )
         return logits
 

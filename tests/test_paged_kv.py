@@ -21,7 +21,7 @@ import pytest
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.models import attention as attn
-from repro.models.model import init_params
+from repro.models.model import decode_step, init_params, prefill
 from repro.serving.batching import Request
 from repro.serving.loop import ServingLoop
 from repro.serving.paged_kv import (
@@ -81,8 +81,9 @@ def _gqa_case(cfg, p, rng, lengths, bs, seq):
     nb, n_blocks, tables = _random_layout(rng, b, seq, bs)
     pool_k = _blockify(rng, cache_k, tables, bs, n_blocks)
     pool_v = _blockify(rng, cache_v, tables, bs, n_blocks)
-    out, pool_k, pool_v = attn.gqa_decode_paged(
-        p, cfg, x, pool_k, pool_v, jnp.asarray(tables), pos
+    out, pool_k, pool_v = attn.gqa_prefill_paged(  # decode: chunk of 1
+        p, cfg, x, pool_k, pool_v, jnp.asarray(tables), pos, pos[:, None],
+        None,
     )
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref_o, np.float32),
@@ -118,8 +119,9 @@ def _mla_case(cfg, p, rng, lengths, bs, seq):
     nb, n_blocks, tables = _random_layout(rng, b, seq, bs)
     pool_c = _blockify(rng, cache_ckv, tables, bs, n_blocks)
     pool_r = _blockify(rng, cache_kr, tables, bs, n_blocks)
-    out, pool_c, pool_r = attn.mla_decode_paged(
-        p, cfg, x, pool_c, pool_r, jnp.asarray(tables), pos
+    out, pool_c, pool_r = attn.mla_prefill_paged(  # decode: chunk of 1
+        p, cfg, x, pool_c, pool_r, jnp.asarray(tables), pos, pos[:, None],
+        None,
     )
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref_o, np.float32),
@@ -575,3 +577,46 @@ def test_paged_loop_serves_recurrent_arch(serve_setup):
     done = loop.run(max_steps=300)
     assert len(done) == 3
     assert all(len(r.generated) == 3 for r in done)
+
+
+def test_recurrent_arch_serving_matches_contiguous_reference():
+    """Jamba (mamba and attention layers) served through ServingLoop in
+    fp32 emits the tokens of the contiguous `prefill` + `decode_step`
+    reference. Each prompt prefills at its exact width from a zero
+    recurrent state (the paged forward handed no states), the 1-token
+    prompt as a chunk of one; decode then steps the rows' states."""
+    cfg = dataclasses.replace(
+        reduce_for_smoke(get_config("jamba-v0.1-52b")),
+        param_dtype="float32", compute_dtype="float32",
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (1, 5, 7)]
+    new, cache_len = 4, 16
+    loop = ServingLoop(cfg, params, batch_size=4, n_groups=1,
+                       cache_len=cache_len, bucket_table=None)
+    widths, prefill_fn = [], loop.engine.prefill_slots_paged
+
+    def record(suffixes, *args):
+        widths.append(np.shape(suffixes)[1])
+        return prefill_fn(suffixes, *args)
+
+    loop.engine.prefill_slots_paged = record
+    for rid, prompt in enumerate(prompts):
+        loop.submit(Request(rid=rid, prompt=prompt, max_new_tokens=new))
+    served = {r.rid: r.generated for r in loop.run(max_steps=100)}
+    assert sorted(widths) == [1, 5, 7]
+    for rid, prompt in enumerate(prompts):
+        n = len(prompt)
+        logits, cache = prefill(
+            params, cfg, {"tokens": jnp.asarray(prompt[None])},
+            cache_len=cache_len, token_mask=jnp.ones((1, n), bool),
+        )
+        want = [int(jnp.argmax(logits[0]))]
+        for t in range(new - 1):
+            logits, cache, _ = decode_step(
+                params, cfg, jnp.asarray([[want[-1]]], jnp.int32), cache, n + t
+            )
+            want.append(int(jnp.argmax(logits[0])))
+        assert served[rid] == want, f"prompt of {n}: {served[rid]} != {want}"

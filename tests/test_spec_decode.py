@@ -264,11 +264,14 @@ def test_spec_serving_identical_to_plain(fp32_setup):
     assert "spec_acc=" in st.summary()
 
 
-def test_spec_requires_paged_prefix_cacheable_arch(fp32_setup):
-    cfg, params = fp32_setup
+def test_spec_requires_paged_prefix_cacheable_arch():
+    """Verify rolls rejected drafts back out of the paged blocks, which
+    a recurrent state cannot follow: spec decode on jamba is refused."""
+    cfg = reduce_for_smoke(get_config("jamba-v0.1-52b"))
+    params = init_params(jax.random.PRNGKey(0), cfg)
     with pytest.raises(AssertionError, match="spec_decode requires"):
         ServingLoop(cfg, params, batch_size=2, n_groups=1, cache_len=16,
-                    kv_layout="slots", spec_decode=True)
+                    spec_decode=True)
 
 
 def test_spec_identity_survives_corrupted_drafts(fp32_setup):

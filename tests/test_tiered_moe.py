@@ -101,12 +101,13 @@ def test_tier_sizes_take_hbm_from_the_tpu_kind(monkeypatch, kind):
 
 
 def test_engine_online_loop_runs():
-    from repro.models.model import init_params, prefill
+    from repro.models.model import init_params
     from repro.serving.engine import (
         TriMoEServingEngine,
         fill_tiers_from_params,
         init_tiered_for_model,
     )
+    from repro.serving.paged_kv import PagedKVCache
 
     cfg = reduce_for_smoke(get_config("granite-moe-1b-a400m"))
     rng = jax.random.PRNGKey(0)
@@ -115,15 +116,25 @@ def test_engine_online_loop_runs():
     tiered = init_tiered_for_model(jax.random.PRNGKey(1), cfg, sizes)
     tiered = fill_tiers_from_params(params, tiered, cfg)
     b, s, new = 2, 8, 6
-    batch = {"tokens": jax.random.randint(rng, (b, s), 0, cfg.vocab_size)}
-    _, cache = prefill(params, cfg, batch, cache_len=s + new)
-    eng = TriMoEServingEngine(cfg, params, cache, tiered, sizes=sizes)
-    tok = batch["tokens"][:, -1:]
+    prompts = np.asarray(jax.random.randint(rng, (b, s), 0, cfg.vocab_size),
+                         np.int32)
+    kv = PagedKVCache(cfg, b, s + new, prefix_cache=False)
+    eng = TriMoEServingEngine(cfg, params, kv, tiered, sizes=sizes)
+    slots = list(range(b))
+    for i in slots:
+        kv.admit_slot(i, prompts[i])
+    logits = eng.prefill_slots_paged(prompts, slots, [s] * b, [0] * b)
     for i in range(new):
-        logits = eng.step(tok, s + i)
         assert np.all(np.isfinite(np.asarray(logits, np.float32)))
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        tok = np.asarray(jnp.argmax(logits, -1), np.int32)[:, None]
+        pos = np.full(b, s + i)
+        for j in slots:
+            kv.ensure_block(j, s + i)
+        logits, counts = eng.step_slots_paged(tok, pos, slots,
+                                              kv.table_rows(slots))
+        eng.replan(counts, eng.last_dispatch)
     assert eng.stats.steps == new
+    assert eng.stats.prefills == b
 
 
 def test_token_mask_excludes_dead_tokens_from_counts(setup):

@@ -12,8 +12,10 @@ of 8 hot, 10 warm and 16 cold slots. The same kernels and migrations
 compile again at GLM-4.5-Air's widths (the `glm-4.5-air-stage` cell):
 96 query heads over 8 kv heads of 128 (G=12), d_model 4096, experts of
 width 1408 held 32 to a chip in 4 MoE layers (8/10/16 slots). The
-paged decode and prefill programs compile with their KV pools donated,
-as the engine runs them.
+paged decode, prefill and verify programs compile with their KV pools
+donated, as the engine runs them, and keep the device ops their
+separate entry points compiled to before the three became one paged
+forward.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
@@ -200,56 +202,85 @@ def test_migrations_update_tier_buffers_in_place_at_glm_widths(s, stacked):
     _assert_migrations_in_place(s, stacked, 4, GLM_D, GLM_F, 128)
 
 
-def _paged_steps(monkeypatch, s, n_layers):
-    """Granite at published widths, `n_layers` deep (one layer per scan
-    group), 4 slots of 64 tokens: shapes of (params, tiers, pools) on the
-    described chip and the per-slot states (empty for attention), with
-    the Pallas kernels on the step's path."""
+def _paged_program(monkeypatch, s, phase, rows, width, slots=4, ctx=64):
+    """The paged forward of `phase` (decode: 1 token a row, prefill: 32,
+    verify: 4) compiled with its KV pools donated, as the engine runs
+    it: granite at published widths, 4 layers deep (one layer per scan
+    group), `slots` slots of `ctx` tokens, `rows` rows over tables cut
+    to `width` columns, with the Pallas kernels on the path. Prefill's
+    expert counts are dropped, as the engine drops them. Returns the
+    optimized HLO and the pools' shapes."""
     import dataclasses
 
     import repro.kernels.backend as kb
     import repro.kernels.paged_attention as pa
     from repro.configs import get_config
-    from repro.models.model import init_params
+    from repro.models.model import forward_paged, init_params
     from repro.serving.engine import init_tiered_for_model, strip_expert_weights
     from repro.serving.paged_kv import init_paged_cache
     from repro.serving.tiered_moe import TierSizes
 
     for mod in (kb, pa):
         monkeypatch.setattr(mod, "resolve_backend", lambda *a, **k: ("pallas", False))
-    cfg = dataclasses.replace(get_config("granite-moe-1b-a400m"), n_layers=n_layers)
+    cfg = dataclasses.replace(get_config("granite-moe-1b-a400m"), n_layers=4)
     key = jax.random.PRNGKey(0)
     params = strip_expert_weights(jax.eval_shape(lambda: init_params(key, cfg)), cfg)
     tiers = jax.eval_shape(lambda: init_tiered_for_model(key, cfg, TierSizes(8, 10, 14)))
-    pools, states = jax.eval_shape(lambda: init_paged_cache(cfg, 4, 4 * 16, BS))
+    pools, states = jax.eval_shape(lambda: init_paged_cache(cfg, slots, ctx, BS))
     place = lambda t: jax.tree.map(lambda a: s(a.shape, a.dtype), t)  # noqa: E731
-    return cfg, place(params), place(tiers), place(pools), states
+    chunk = {"decode": 1, "prefill": 32, "verify": 4}[phase]
+    toks, mask = jnp.zeros((rows, chunk), I32), jnp.ones((rows, chunk), bool)
+    # decode steps the rows' (here empty) recurrent state; chunks start it
+    rows_state = None if chunk > 1 else jax.tree.map(
+        lambda a: jnp.zeros((rows,) + a.shape[1:], a.dtype), states)
+
+    def fn(p, pl, t, past, ts):
+        out = forward_paged(p, cfg, toks, pl, rows_state, t, past, mask, ts,
+                            all_logits=phase == "verify")
+        return out[:3] if phase == "prefill" else out
+
+    hlo = jax.jit(fn, donate_argnums=1).lower(
+        place(params), place(pools), s((rows, width), I32), s((rows,), I32),
+        place(tiers)).compile().as_text()
+    return hlo, jax.tree.leaves(pools)
 
 
-@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("phase", ["decode", "prefill", "verify"])
 def test_paged_steps_update_the_kv_pool_in_place(monkeypatch, s, phase):
     """With the pools donated, as the engine donates them, the paged
-    decode and prefill programs alias the pool input to the output and
-    copy no whole stacked pool: the scan carries the pools and writes
-    each group's back in place. (As a scan output the new pools were a
-    fresh buffer, and donation cost one whole-pool copy per step.)"""
-    from repro.models.model import decode_step_paged, prefill_paged
-
-    cfg, params, tiers, pools, states = _paged_steps(monkeypatch, s, 4)
-    rows, chunk, width = 4, (1 if phase == "decode" else 32), 16
-    tables, pos = s((rows, width), I32), s((rows,), I32)
-    if phase == "decode":
-        fn = lambda p, pl, t, ps, ts: decode_step_paged(  # noqa: E731
-            p, cfg, jnp.zeros((rows, 1), I32), pl, states, t, ps,
-            tiered=ts, cold_capacity_frac=1.0)
-    else:
-        fn = lambda p, pl, t, ps, ts: prefill_paged(  # noqa: E731
-            p, cfg, {"tokens": jnp.zeros((rows, chunk), I32)}, pl, t, ps,
-            jnp.ones((rows, chunk), bool), tiered=ts, cold_capacity_frac=1.0)
-    hlo = jax.jit(fn, donate_argnums=1).lower(
-        params, pools, tables, pos, tiers).compile().as_text()
+    decode, prefill and verify programs alias the pool input to the
+    output and copy no whole stacked pool: the scan carries the pools
+    and writes each group's back in place. (As a scan output the new
+    pools were a fresh buffer, and donation cost one whole-pool copy per
+    step.)"""
+    hlo, pools = _paged_program(monkeypatch, s, phase, rows=4, width=16)
     assert "input_output_alias" in hlo and "tpu_custom_call" in hlo
-    whole = {f"bf16[{','.join(map(str, a.shape))}]" for a in jax.tree.leaves(pools)}
+    whole = {f"bf16[{','.join(map(str, a.shape))}]" for a in pools}
     outs = [m.group(1) for m in re.finditer(r"= (.*?) copy(?:-start)?\(", hlo)]
     copies = [o for o in outs if any(w in o for w in whole)]
     assert not copies, copies[:3]
+
+
+@pytest.mark.parametrize("phase,rows,ops", [
+    ("decode", 64, (4, 12, 4)),
+    ("prefill", 4, (7, 11, 4)),
+    ("verify", 64, (7, 12, 4)),
+])
+def test_paged_programs_keep_their_device_ops(monkeypatch, s, phase, rows, ops):
+    """Decode, prefill and verify are one paged forward, and each phase
+    compiles to the device work its own entry point compiled to before
+    they merged: the same number of Pallas calls (attention and expert
+    FFNs), scatters (K/V writes and expert dispatch) and relayout copies
+    of a group's pool in the kernel's lane view, [N+1, 4, 4, 128]. The
+    shapes are decode-long's: 64 slots of 1152 tokens (18433 blocks
+    with the trash block), tables cut to 64 columns."""
+    hlo, pools = _paged_program(monkeypatch, s, phase, rows=rows, width=64,
+                                slots=64, ctx=1152)
+    lane = f"bf16[{pools[0].shape[1]},{BS},{KV // 2},{2 * HD}]"
+    copies = [m for m in re.finditer(r"= (\S+) copy(?:-start)?\(", hlo)
+              if m.group(1).startswith(lane)]
+    assert (
+        hlo.count('custom_call_target="tpu_custom_call"'),
+        len(re.findall(r"= [^=]*? scatter\(", hlo)),
+        len(copies),
+    ) == ops

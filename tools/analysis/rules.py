@@ -392,7 +392,7 @@ def rl004(path: str, tree: ast.AST, lines: Sequence[str]) -> List[Finding]:
 _RL005_SCOPE = ("src/repro/models/attention.py", "src/repro/kernels/paged_attention/")
 # the ONLY functions allowed to scatter into paged pools: both route
 # pad/dead-row writes to the sentinel trash block
-_SCATTER_ALLOWLIST = {"_paged_write", "paged_scatter"}
+_SCATTER_ALLOWLIST = {"paged_scatter"}
 _WRITE_METHODS = {"set", "add", "multiply", "divide", "max", "min", "apply"}
 
 
